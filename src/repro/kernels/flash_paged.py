@@ -85,14 +85,15 @@ def paged_impl() -> tuple[str, bool]:
     return "xla", False
 
 
-def _deq(ref, scale_ref, bs):
+def _deq(ref, scale_ref):
     """One page (1, bs, F) in storage dtype → (bs, F) f32, dequantized.
 
     Same float op as the XLA twin's pool read: ``int8 → f32 * scale[token]``
-    with the per-token scale broadcast over every feature."""
+    with the per-token scale — a (1, bs, 1) block, so the (bs, 1) column
+    broadcasts over every feature lane — applied to every feature."""
     page = ref[0]
     if page.dtype == jnp.int8:
-        return page.astype(jnp.float32) * scale_ref[0].reshape(bs, 1)
+        return page.astype(jnp.float32) * scale_ref[0]
     return page.astype(jnp.float32)
 
 
@@ -137,8 +138,8 @@ def _kernel(
 
     # dequantized page: K parts concatenated on features (MLA [ckv ; kr]),
     # V taken whole — each laid out (bs, kv * per-head-features)
-    parts = [_deq(r, s, bs) for r, s in zip(k_refs, ks_refs)]
-    v_page = _deq(v_ref, vs_ref, bs)
+    parts = [_deq(r, s) for r, s in zip(k_refs, ks_refs)]
+    v_page = _deq(v_ref, vs_ref)
 
     # scores per kv head: q rows [g*group*sq, (g+1)*group*sq) dot that head's
     # feature slice of every part
@@ -235,22 +236,22 @@ def flash_paged_decode(
     def page_map(b, m, tbl, _pos, _len):
         return (tbl[b, m], 0, 0)
 
-    def page_map2(b, m, tbl, _pos, _len):
-        return (tbl[b, m], 0)
-
     in_specs = [pl.BlockSpec((1, hq, hd_tot), lambda b, m, *_: (b, 0, 0))]
     operands: list = [qf]
+    # int8 page scales ride as (P+1, bs, 1) columns: a (1, bs, 1) block spans
+    # the array's last two dims, which Mosaic accepts where a (1, bs) row
+    # block over (P+1, bs) is refused, and lands in VMEM already (bs, 1)
     for part, scale in zip(k_parts, k_scales):
         in_specs.append(pl.BlockSpec((1, bs, part.shape[2]), page_map))
         operands.append(part)
         if k_int8:
-            in_specs.append(pl.BlockSpec((1, bs), page_map2))
-            operands.append(scale)
+            in_specs.append(pl.BlockSpec((1, bs, 1), page_map))
+            operands.append(scale[..., None])
     in_specs.append(pl.BlockSpec((1, bs, v_pool.shape[2]), page_map))
     operands.append(v_pool)
     if v_int8:
-        in_specs.append(pl.BlockSpec((1, bs), page_map2))
-        operands.append(v_scale)
+        in_specs.append(pl.BlockSpec((1, bs, 1), page_map))
+        operands.append(v_scale[..., None])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

@@ -57,5 +57,7 @@ def unpack_plane(packed: jnp.ndarray, bits: int, plane: int) -> jnp.ndarray:
     if not 0 <= plane < planes:
         raise ValueError(f"plane {plane} out of range for {bits}-bit")
     shift_up = 8 - (plane + 1) * bits
-    # arithmetic right shift of int8 sign-extends
-    return (packed.astype(jnp.int8) << shift_up) >> (8 - bits)
+    # shift in int32 (Mosaic has no int8 shifts): move the plane to the top
+    # byte, then the arithmetic right shift sign-extends it
+    p = packed.astype(jnp.int8).astype(jnp.int32)
+    return ((p << (24 + shift_up)) >> (32 - bits)).astype(jnp.int8)

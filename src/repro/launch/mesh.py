@@ -5,7 +5,20 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
+
+
+def make_mesh(shape: tuple, axes: tuple, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The logical-axis layer (parallel/sharding.py ``constrain``) places
+    arrays with ``with_sharding_constraint``, which only accepts Auto axes;
+    ``jax.make_mesh`` defaults to Explicit axes on current JAX."""
+    return jax.make_mesh(
+        shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,11 +27,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism across the slower inter-pod (DCN-class) links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU examples)."""
-    n = len(jax.devices())
-    assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over the first ``data * model`` devices (tests / CPU
+    examples / one-chip serving)."""
+    devs = jax.devices()
+    assert data * model <= len(devs), (data, model, len(devs))
+    return make_mesh((data, model), ("data", "model"), devices=devs[: data * model])

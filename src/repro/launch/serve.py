@@ -38,10 +38,11 @@ from ..serve import (
     Scheduler,
     install_sigint_drain,
 )
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)
@@ -119,8 +120,16 @@ def main(argv=None):
                          "(TensorBoard/Perfetto); the jitted steps carry "
                          "serve/* named scopes that line up with --trace "
                          "spans by name")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build_engine(args: argparse.Namespace):
+    """Model, weights and serving engine for parsed serve arguments.
+
+    Returns ``(cfg, rc, engine, use_scheduler)``. Weights are random, from
+    ``args.seed``. Call inside ``use_mesh(make_local_mesh(args.data,
+    args.model))`` and run the engine there too: this is the construction
+    every serve run shares (``main`` here, ``chip_smoke.py``)."""
     cfg = get_config(args.arch)
     on_cpu = jax.default_backend() == "cpu"
     dtype = "float32" if on_cpu else "bfloat16"
@@ -136,9 +145,6 @@ def main(argv=None):
         spec_gamma=args.spec_gamma,
         draft_policy=load_policy(args.draft_policy) if args.spec_gamma else None,
     )
-    mesh = make_local_mesh(args.data, args.model)
-    rng = np.random.default_rng(args.seed)
-
     use_scheduler = args.engine == "scheduler" and cfg.family not in ("ssm", "hybrid")
     if args.engine == "scheduler" and not use_scheduler:
         print(f"[serve] {cfg.family} mixer state is not chunk-resumable — "
@@ -161,55 +167,66 @@ def main(argv=None):
         print("[serve] speculative decoding is single-device: disabling --spec-gamma")
         rc = dataclasses.replace(rc, spec_gamma=0, draft_policy=None)
 
-    with use_mesh(mesh):
-        params = init(cfg, rc, jax.random.PRNGKey(args.seed))
-        # the draft weight view must derive from the float tree BEFORE the
-        # target policy's surgery packs any leaf (packed leaves pin their own
-        # bitwidth and would silently run the draft at target precision) —
-        # hand the Scheduler the pre-surgery params for its SpecDecoder
-        draft_params = params if (use_scheduler and rc.spec_gamma) else None
-        # pack any prequant rules offline (identity for dynamic/bf16
-        # policies) — without this the engine would silently fall back to
-        # quantize-on-load for weights the policy pinned as plane-packed
-        from ..quant import apply_surgery
+    params = init(cfg, rc, jax.random.PRNGKey(args.seed))
+    # the draft weight view must derive from the float tree BEFORE the
+    # target policy's surgery packs any leaf (packed leaves pin their own
+    # bitwidth and would silently run the draft at target precision) —
+    # hand the Scheduler the pre-surgery params for its SpecDecoder
+    draft_params = params if (use_scheduler and rc.spec_gamma) else None
+    # pack any prequant rules offline (identity for dynamic/bf16
+    # policies) — without this the engine would silently fall back to
+    # quantize-on-load for weights the policy pinned as plane-packed
+    from ..quant import apply_surgery
 
-        params = apply_surgery(cfg, rc, params)
-        if use_scheduler:
-            adm = AdmissionController(
-                max_queue=args.queue_bound or None,
-                tenant_budgets=({"default": args.tenant_budget}
-                                if args.tenant_budget else None),
-                default_ttl=args.ttl_ticks or None,
-            )
-            tracer = None
-            if args.trace:
-                from ..obs.trace import Tracer
+    params = apply_surgery(cfg, rc, params)
+    if not use_scheduler:
+        eng = Engine(
+            cfg, rc, params,
+            capacity=args.capacity, max_batch=args.max_batch,
+            temperature=args.temperature, seed=args.seed,
+        )
+        return cfg, rc, eng, False
+    adm = AdmissionController(
+        max_queue=args.queue_bound or None,
+        tenant_budgets=({"default": args.tenant_budget}
+                        if args.tenant_budget else None),
+        default_ttl=args.ttl_ticks or None,
+    )
+    tracer = None
+    if args.trace:
+        from ..obs.trace import Tracer
 
-                tracer = Tracer()
-            eng = Scheduler(
-                cfg, rc, params,
-                capacity=args.capacity, max_batch=args.max_batch,
-                num_pages=args.num_pages or None,
-                temperature=args.temperature, seed=args.seed,
-                draft_params=draft_params,
-                admission=adm, track_energy=args.energy,
-                mesh=args.mesh, tracer=tracer,
-            )
-        else:
-            eng = Engine(
-                cfg, rc, params,
-                capacity=args.capacity, max_batch=args.max_batch,
-                temperature=args.temperature, seed=args.seed,
-            )
-        rejected = 0
-        for rid in range(args.requests):
-            prompt = rng.integers(0, cfg.vocab_size, size=args.prompt_len).tolist()
-            req = Request(rid=rid, prompt=prompt, max_new=args.max_new)
-            if use_scheduler:
-                req.priority = args.priority
-                rejected += eng.submit(req) is not None
-            else:
-                eng.submit(req)
+        tracer = Tracer()
+    eng = Scheduler(
+        cfg, rc, params,
+        capacity=args.capacity, max_batch=args.max_batch,
+        num_pages=args.num_pages or None,
+        temperature=args.temperature, seed=args.seed,
+        draft_params=draft_params,
+        admission=adm, track_energy=args.energy,
+        mesh=args.mesh, tracer=tracer,
+    )
+    return cfg, rc, eng, True
+
+
+def synthetic_requests(cfg, args: argparse.Namespace) -> list[Request]:
+    """``args.requests`` prompts of ``args.prompt_len`` random tokens."""
+    rng = np.random.default_rng(args.seed)
+    reqs = []
+    for rid in range(args.requests):
+        prompt = rng.integers(0, cfg.vocab_size, size=args.prompt_len).tolist()
+        reqs.append(Request(rid=rid, prompt=prompt, max_new=args.max_new,
+                            priority=args.priority))
+    return reqs
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    with use_mesh(make_local_mesh(args.data, args.model)):
+        cfg, rc, eng, use_scheduler = build_engine(args)
+        for req in synthetic_requests(cfg, args):
+            eng.submit(req)
         # graceful shutdown: first ^C drains active slots (energy summaries
         # and health counters survive), second ^C aborts hard
         restore = install_sigint_drain(eng) if use_scheduler else None

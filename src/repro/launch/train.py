@@ -32,6 +32,7 @@ from ..configs.base import SHAPES, RunConfig, ShapeConfig, get_config
 from ..data import make_batches
 from ..parallel.sharding import use_mesh
 from ..train import Trainer
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh, make_production_mesh
 
 
@@ -61,6 +62,7 @@ def main(argv=None):
     ap.add_argument("--model", type=int, default=1, help="local mesh model-axis size")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     on_cpu = jax.default_backend() == "cpu"

@@ -19,22 +19,18 @@ Scope taxonomy::
     serve/logits        the lm-head projection inside any of the above
 
 ``jax.profiler.start_trace`` needs a writable logdir and is unavailable on
-some backends; :func:`device_trace` degrades to a warning-once no-op rather
-than failing a serve run that only wanted host tracing.
+some backends; :func:`device_trace` then raises, so a run that asked for a
+device trace never ends as if it had one. Host tracing (obs/trace.py) does
+not go through here.
 """
 
 from __future__ import annotations
 
-import logging
 from contextlib import contextmanager
 
 import jax
 
 __all__ = ["named_scope", "device_trace"]
-
-log = logging.getLogger("repro.obs")
-
-_warned = False
 
 
 def named_scope(name: str):
@@ -44,28 +40,19 @@ def named_scope(name: str):
 
 @contextmanager
 def device_trace(logdir: str | None):
-    """Wrap a block in ``jax.profiler.trace(logdir)`` when ``logdir`` is
-    set; no-op (with one warning on failure) otherwise. The captured device
-    trace is viewable in Perfetto/TensorBoard and carries the serve/*
-    named scopes above."""
-    global _warned
+    """Wrap a block in a ``jax.profiler`` trace written to ``logdir`` when
+    it is set; no-op otherwise. The captured device trace is viewable in
+    Perfetto/TensorBoard and carries the serve/* named scopes above.
+
+    Raises ``RuntimeError`` when the profiler cannot start."""
     if not logdir:
         yield
         return
-    started = False
     try:
         jax.profiler.start_trace(logdir)
-        started = True
-    except Exception as e:  # noqa: BLE001 - profiling must never kill serving
-        if not _warned:
-            _warned = True
-            log.warning("obs: jax.profiler unavailable (%r) — device trace "
-                        "disabled, host tracing unaffected", e)
+    except Exception as e:  # noqa: BLE001 - re-raised with the logdir named
+        raise RuntimeError(f"device trace could not start in {logdir!r}: {e!r}") from e
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # noqa: BLE001
-                log.warning("obs: jax.profiler.stop_trace failed: %r", e)
+        jax.profiler.stop_trace()

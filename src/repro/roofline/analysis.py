@@ -60,13 +60,14 @@ HW_PROFILES: dict[str, HW] = {
 def hw_profile(name: str | None = None) -> HW:
     """Resolve a named :class:`HW` profile.
 
-    ``None`` / ``"auto"`` selects by the running JAX backend (tpu/gpu/cpu;
-    unknown backends fall back to the tpu assignment target). The import is
-    lazy so artifact-only re-pricing never initializes a device runtime."""
+    ``None`` / ``"auto"`` selects by the running JAX backend (tpu/gpu/cpu);
+    a backend with no profile is an error, never priced as another device.
+    The import is lazy so artifact-only re-pricing never initializes a
+    device runtime."""
     if name in (None, "auto"):
         import jax
 
-        return HW_PROFILES.get(jax.default_backend(), HW_PROFILES["tpu"])
+        name = jax.default_backend()
     prof = HW_PROFILES.get(name)
     if prof is None:
         raise KeyError(

@@ -580,6 +580,10 @@ class Scheduler:
         self._tables_dev = None          # device copy of mgr.tables ...
         self._tables_version = -1        # ... keyed on mgr.version
         self._rr = 0                     # rotating plan start (fairness)
+        # optional callable(rids, logits): each non-speculative tick's
+        # scheduled request ids and a copy of their (len(rids), V) f32
+        # logits, before sampling (e.g. to compare two engines' numerics)
+        self.logits_hook = None
 
         # --- prefix cache (DESIGN.md §11) ---
         self.prefix_hits = 0             # admissions that forked a cached prefix
@@ -1249,6 +1253,9 @@ class Scheduler:
             else:
                 for i in main_rows:
                     logits_np[i] = main_np[i]
+        if self.logits_hook is not None:
+            self.logits_hook([self.slots[i].req.rid for i in scheduled],
+                             logits_np[scheduled])
         self.ticks += 1
         n_prefill = sum(int(lens[i]) for i in prefill_rows)
         self.prefill_tokens_computed += n_prefill

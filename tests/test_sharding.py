@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import RunConfig, ShapeConfig, get_config
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import DEFAULT_RULES, spec_for, use_mesh
 from repro.parallel.state_sharding import (
     abstract_caches,
@@ -29,7 +30,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def test_spec_for_divisibility_and_dedup():
