@@ -118,8 +118,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace into DIR "
                          "(TensorBoard/Perfetto); the jitted steps carry "
-                         "serve/* named scopes that line up with --trace "
-                         "spans by name")
+                         "serve/* named scopes, and with --trace every tick "
+                         "phase is a serve/<phase> annotation on the "
+                         "profile's host line, on the device's clock")
     return ap.parse_args(argv)
 
 

@@ -11,9 +11,13 @@ Span taxonomy (DESIGN.md §14):
   ``reject`` (reason in args).
 - **Scheduler track** (pid :data:`PID_SCHED`, tid 0): one ``tick`` span per
   :meth:`Scheduler.tick` with nested phase spans — ``admit``, ``plan``,
-  ``cow_drain``, ``device_step`` (ends at the host-side logits
+  ``cow_drain``, ``tables``, ``device_step`` (ends at the host-side logits
   materialization, i.e. the device sync), ``commit`` — and for spec ticks
-  ``draft`` / ``verify`` phases.
+  ``draft`` / ``verify`` phases. A plain tick's ``device_step`` holds
+  ``step_inputs`` / ``step_launch`` / ``step_wait`` / ``logits_fetch`` /
+  ``logits_widen``, its ``commit`` holds ``logits_check`` / ``sample`` /
+  ``emit``; a backend compile anywhere in the process records a
+  ``compile`` span (obs/profile.watch_compiles).
 - **Counter tracks** (pid :data:`PID_SCHED`): ``pool_pages`` (in_use/live),
   ``queue_depth`` (per priority class), ``ladder_level``, and under
   ``track_energy`` ``modeled_power_mw`` + ``modeled_energy_mj`` — the
@@ -22,11 +26,15 @@ Span taxonomy (DESIGN.md §14):
   request slow" and "what did it cost in modeled mW" in one Perfetto view.
 
 Timestamps are host ``perf_counter_ns`` relative to tracer construction, in
-microseconds (the trace-event unit). The tracer is append-only host-side
-bookkeeping: when disabled (:data:`NULL_TRACER`) every call is a no-op and
-the scheduler additionally skips arg-dict construction, so the disabled
-cost is one attribute test per site (<3% decode tokens/s is enforced by
-benchmarks/obs_bench.py; bit-exactness of tokens by tests/test_obs.py).
+microseconds (the trace-event unit). Every scheduler-track span opened with
+:meth:`Tracer.span` also opens a ``jax.profiler.TraceAnnotation`` named
+``serve/<span>``, so in any ``jax.profiler`` capture the same phases sit on
+the profiler's host line, on the device's clock. The tracer is append-only
+host-side bookkeeping: when disabled (:data:`NULL_TRACER`) every call is a
+no-op, ``span`` returns one shared null context, and the scheduler
+additionally skips arg-dict construction, so the disabled cost is one
+attribute test or one null ``with`` per site (bit-exactness of tokens is
+pinned by tests/test_obs.py).
 
 Export is the Chrome trace-event "JSON object format"::
 
@@ -41,6 +49,8 @@ from __future__ import annotations
 import json
 import time
 from contextlib import nullcontext
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -63,22 +73,32 @@ _NULL_CTX = nullcontext()
 class _Span:
     """Hand-rolled context manager for :meth:`Tracer.span` — a plain class
     beats ``@contextmanager`` ~3x on enter/exit, and span() sits on the
-    per-tick hot path."""
+    per-tick hot path. A scheduler-track span also holds the profiler
+    annotation ``serve/<name>`` open while it runs. ``args`` may be set
+    inside the block (recorded at exit); ``ts`` / ``dur`` hold the interval
+    once the block has exited."""
 
-    __slots__ = ("_tr", "_name", "_pid", "_tid", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_pid", "_tid", "_cat", "args", "_ann",
+                 "ts", "dur")
 
     def __init__(self, tr, name, pid, tid, cat, args):
         self._tr, self._name, self._pid, self._tid = tr, name, pid, tid
-        self._cat, self._args = cat, args
+        self._cat, self.args = cat, args
+        self._ann = TraceAnnotation("serve/" + name) if pid == PID_SCHED else None
 
     def __enter__(self):
-        self._t0 = self._tr.ts()
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.ts = self._tr.ts()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        tr._raw.append(("X", self._name, self._pid, self._tid, self._t0,
-                        tr.ts() - self._t0, self._cat, self._args))
+        self.dur = tr.ts() - self.ts
+        tr._raw.append(("X", self._name, self._pid, self._tid, self.ts,
+                        self.dur, self._cat, self.args))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -198,7 +218,7 @@ class NullTracer:
     def complete(self, *a, **k) -> None:
         pass
 
-    def span(self, *a, **k):
+    def span(self, name, pid=PID_SCHED, tid=TID_TICK, cat="serve", args=None):
         return _NULL_CTX
 
     def instant(self, *a, **k) -> None:

@@ -58,7 +58,7 @@ from ..models import KVView, forward, init_caches, lm_logits
 from ..models.transformer import plan_groups
 from ..obs.logs import kv
 from ..obs.metrics import MetricsRegistry, family_percentile as _family_percentile
-from ..obs.profile import named_scope
+from ..obs.profile import compile_count, named_scope, watch_compiles
 from ..obs.trace import NULL_TRACER, PID_REQUESTS, PID_SCHED, TID_TICK
 from ..parallel.sharding import current_ctx as sharding_ctx
 from ..quant import capture as stats_capture
@@ -483,6 +483,8 @@ class Scheduler:
         from ..kernels import ops as _kops
         self._kops = _kops
         self._kernel_base = _kops.kernel_counters()
+        # backend compiles are process-global too: count from here
+        self._compile_base = watch_compiles(self.trace)
         self._t_submit: dict[int, float] = {}    # rid -> wall time at submit
         self._t_queued: dict[int, float] = {}    # rid -> tracer ts at enqueue
         self._t_emit: dict[int, float] = {}      # rid -> wall time, last emit
@@ -636,10 +638,8 @@ class Scheduler:
             "serve_itl_seconds",
             "wall time between consecutive emitted tokens", labels=("priority",))
         self._h_queue_wait = m.histogram(
-            "serve_queue_wait_ticks",
-            "logical ticks spent queued before (re)admission",
-            labels=("priority",),
-            buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024))
+            "serve_queue_wait_seconds",
+            "wall time from submit to (re)admission", labels=("priority",))
         self._h_tick = m.histogram(
             "serve_tick_seconds", "wall duration of one tick() call")
         self._c_sched_tokens = m.counter(
@@ -773,8 +773,10 @@ class Scheduler:
                 )
                 self.slots[i] = sl
                 self._admit_counter += 1
-                self._h_queue_wait.labels(req.priority).observe(
-                    max(self.clock - req.submitted_tick, 0))
+                t_sub = self._t_submit.get(req.rid)
+                if t_sub is not None:
+                    self._h_queue_wait.labels(req.priority).observe(
+                        time.perf_counter() - t_sub)
                 if self.trace.enabled:
                     tr = self.trace
                     now = tr.ts()
@@ -1132,39 +1134,35 @@ class Scheduler:
         self._fault_fired = False
         self._stall_this_tick = False
         tr = self.trace
-        _pt = tr.ts()
-        if self.faults is not None:
-            self._apply_tick_faults()
-        if self.admission.queue_pressure():
-            # a bounded queue at its limit is the signal that can push the
-            # ladder past preempt into shed/reject
-            self.ladder.note_pressure(self.clock, "queue_full")
-        if self.ladder.level >= 4:
-            # ladder level 4: shed queued work that cannot or should not run
-            # — expired requests and the whole batch class
-            self.admission.shed_expired(self.clock)
-            self.admission.shed_class("batch", self.clock)
-        self._admit()
-        if tr.enabled:
-            now = tr.ts()
-            tr.complete("admit", PID_SCHED, TID_TICK, _pt, now - _pt)
-            _pt = now
-        tokens, pos, lens, decode_rows, prefill_rows, stalled = self._plan()
-        if stalled:
-            self._note_stall(stalled)
-        # pool pressure: nothing schedulable while slots are active means
-        # every row's page allocation failed — recompute-preempt until one
-        # can proceed (bounded by max_batch-1 preemptions)
-        while not (decode_rows or prefill_rows) and self._preempt_one():
+        with tr.span("admit"):
+            if self.faults is not None:
+                self._apply_tick_faults()
+            if self.admission.queue_pressure():
+                # a bounded queue at its limit is the signal that can push the
+                # ladder past preempt into shed/reject
+                self.ladder.note_pressure(self.clock, "queue_full")
+            if self.ladder.level >= 4:
+                # ladder level 4: shed queued work that cannot or should not run
+                # — expired requests and the whole batch class
+                self.admission.shed_expired(self.clock)
+                self.admission.shed_class("batch", self.clock)
+            self._admit()
+        with tr.span("plan") as plan_span:
             tokens, pos, lens, decode_rows, prefill_rows, stalled = self._plan()
             if stalled:
                 self._note_stall(stalled)
-        scheduled = decode_rows + prefill_rows
-        if tr.enabled:
-            tr.complete("plan", PID_SCHED, TID_TICK, _pt, tr.ts() - _pt,
-                        args={"decode_rows": len(decode_rows),
-                              "prefill_rows": len(prefill_rows),
-                              "stalled": stalled})
+            # pool pressure: nothing schedulable while slots are active means
+            # every row's page allocation failed — recompute-preempt until one
+            # can proceed (bounded by max_batch-1 preemptions)
+            while not (decode_rows or prefill_rows) and self._preempt_one():
+                tokens, pos, lens, decode_rows, prefill_rows, stalled = self._plan()
+                if stalled:
+                    self._note_stall(stalled)
+            scheduled = decode_rows + prefill_rows
+            if tr.enabled:
+                plan_span.args = {"decode_rows": len(decode_rows),
+                                  "prefill_rows": len(prefill_rows),
+                                  "stalled": stalled}
         if not scheduled:
             if any(s is not None for s in self.slots):
                 if self._fault_fired:
@@ -1184,7 +1182,8 @@ class Scheduler:
                 self._spec_tick(tokens, pos, lens, decode_rows, prefill_rows))
         with tr.span("cow_drain"):
             self._drain_cow()
-        tables = self._tables()
+        with tr.span("tables"):
+            tables = self._tables()
 
         # width-adaptive tick: decode-only ticks run the step at width 1
         # (decode rows only occupy column 0) instead of paying the full
@@ -1211,123 +1210,155 @@ class Scheduler:
                 if not scheduled:
                     return self._end_tick(True)
         main_rows = [i for i in scheduled if i not in fbset]
-        step_by_bits: dict = {}
         # writable host copy: fault injection + row merging mutate it
         logits_np = None if fb_np is None else fb_np.copy()
-        _st = tr.ts()
-        if main_rows:
-            lens_main = lens.copy()
-            for i in fbset:
-                lens_main[i] = 0
-            out = self._step(
-                self.params, self.caches,
-                jnp.asarray(tokens[:, :width]), jnp.asarray(pos),
-                jnp.asarray(lens_main), tables,
-            )
-            if self.mesh is not None:
-                # sharded step always returns the 3-tuple: the raw stats
-                # tree carries per-device leading (dp, tp) axes plus the MoE
-                # drop counters even when energy tracking is off
-                self.caches, logits, raw = out
-                raw_np = jax.tree.map(np.asarray, raw)
-                self.moe_dropped_tokens += self._mesh_step.moe_drops(raw_np)
-                self._accum_comms(self._mesh_step.comms_for(width))
-                if self.track_energy:
-                    tree = self._mesh_step.merge_stats(raw_np)
-                    step_by_bits = tree_totals_by_bits(tree)
-                    self._accum_device_load(
-                        self._mesh_step.device_serial_by_bits(raw_np))
-            elif self.track_energy:
-                self.caches, logits, tree = out
-                step_by_bits = tree_totals_by_bits(tree)
-            else:
-                self.caches, logits = out
-            for b, d in step_by_bits.items():
-                acc = self.cycles_by_bits.setdefault(
-                    b, {"serial_cycles": 0, "parallel_cycles": 0})
-                for k2, v2 in d.items():
-                    acc[k2] += int(v2)
-            main_np = np.array(logits, np.float32)   # writable copy
-            if logits_np is None:
-                logits_np = main_np
-            else:
-                for i in main_rows:
-                    logits_np[i] = main_np[i]
-        if self.logits_hook is not None:
-            self.logits_hook([self.slots[i].req.rid for i in scheduled],
-                             logits_np[scheduled])
-        self.ticks += 1
-        n_prefill = sum(int(lens[i]) for i in prefill_rows)
-        self.prefill_tokens_computed += n_prefill
-        if n_prefill:
-            self._c_sched_tokens.labels("prefill").inc(n_prefill)
-        if decode_rows:
-            self._c_sched_tokens.labels("decode").inc(len(decode_rows))
-        if self.track_energy:
-            self._note_step_energy(step_by_bits, bucket="target")
-        if tr.enabled:
-            # device_step ends at the host logits materialization (the sync)
-            _sdur = tr.ts() - _st
-            tr.complete("device_step", PID_SCHED, TID_TICK, _st, _sdur, args={
+        # device_step ends at the host logits materialization (the sync)
+        with tr.span("device_step", args={
                 "rows": len(main_rows), "width": width,
-                "tokens": int(sum(int(lens[i]) for i in scheduled))})
+                "tokens": int(sum(int(lens[i]) for i in scheduled))}
+                if tr.enabled else None) as step_span:
+            if main_rows:
+                main_np, step_by_bits = self._main_step(
+                    tokens, pos, lens, tables, fbset, width)
+                if logits_np is None:
+                    logits_np = main_np
+                else:
+                    for i in main_rows:
+                        logits_np[i] = main_np[i]
+            else:
+                step_by_bits = {}
+            if self.logits_hook is not None:
+                self.logits_hook([self.slots[i].req.rid for i in scheduled],
+                                 logits_np[scheduled])
+            self.ticks += 1
+            n_prefill = sum(int(lens[i]) for i in prefill_rows)
+            self.prefill_tokens_computed += n_prefill
+            if n_prefill:
+                self._c_sched_tokens.labels("prefill").inc(n_prefill)
+            if decode_rows:
+                self._c_sched_tokens.labels("decode").inc(len(decode_rows))
+            if self.track_energy:
+                self._note_step_energy(step_by_bits, bucket="target")
+        if tr.enabled:
             for i in scheduled:
                 sl = self.slots[i]
                 if sl is None:
                     continue
                 tr.complete(
                     "prefill" if i in prefill_rows else "decode",
-                    PID_REQUESTS, sl.req.rid, _st, _sdur,
+                    PID_REQUESTS, sl.req.rid, step_span.ts, step_span.dur,
                     args={"rid": sl.req.rid, "pos": int(pos[i]),
                           "tokens": int(lens[i]),
                           **({"path": "fallback"} if i in fbset else {})})
-        _ct = tr.ts()
-
-        # induced numerical faults corrupt target-policy rows only (the
-        # fallback step models the numerically-safe path)
-        if self.faults is not None:
-            for ev in self.faults.at(self.clock, "nan_logits"):
-                r = ev.arg % self.max_batch
-                if r in main_rows:
-                    logits_np[r] = np.nan
-        bad = [i for i in scheduled if not np.isfinite(logits_np[i]).all()]
-        for i in bad:
-            if self.slots[i].fallback:
-                # the numerically-safe path itself is non-finite: terminal
-                self._shed_slot(i, RejectReason.NUMERICAL_FAULT,
-                                "non-finite logits at the fallback policy")
-            else:
-                self._quarantine(i)
-        badset = set(bad)
-
-        toks = np.asarray(sample(self._sample_keys(pos, lens),
-                                 jnp.asarray(logits_np), self.temperature))
-
-        total = float(sum(int(lens[i]) for i in main_rows)) or 1.0
-        for i in scheduled:
-            sl = self.slots[i]
-            if sl is None:
-                continue  # shed this tick (terminal numerical fault)
-            if (self.track_energy and sl.meter is not None
-                    and i not in fbset):
-                # quarantined rows stay charged: wasted compute is real
-                sl.meter.add_share(step_by_bits, int(lens[i]) / total)
-            if i in badset:
-                continue  # quarantined: same position retries next tick
-            was_decoding = not sl.prefilling
-            sl.pos += int(lens[i])
-            sl.retries = 0
-            if was_decoding or not sl.prefilling:
-                # decode rows and just-completed prefills both sampled a token
-                self._emit(i, int(toks[i]))
-                if len(sl.req.out) >= sl.req.max_new or sl.pos >= self.capacity - 1:
-                    self._finish(i)
-                    continue
-            self._register_prefix(i)
-        self._rr = (self._rr + 1) % self.max_batch
-        if tr.enabled:
-            tr.complete("commit", PID_SCHED, TID_TICK, _ct, tr.ts() - _ct)
+        with tr.span("commit"):
+            self._commit(logits_np, scheduled, main_rows, fbset, pos, lens,
+                         step_by_bits)
         return self._end_tick(True)
+
+    def _main_step(self, tokens, pos, lens, tables, fbset, width):
+        """The target-policy step over every scheduled row outside ``fbset``.
+        Returns its last-column logits as a writable (B, V) f32 host array
+        and the step's cycle totals by bitwidth.
+
+        Sub-spans of ``device_step``: ``step_inputs`` (the uploads),
+        ``step_launch`` (dispatch), ``step_wait`` (the device, waited for
+        only while tracing), ``logits_fetch`` (device to host) and
+        ``logits_widen`` (the host f32 copy)."""
+        tr = self.trace
+        step_by_bits: dict = {}
+        with tr.span("step_inputs"):
+            lens_main = lens.copy()
+            for i in fbset:
+                lens_main[i] = 0
+            tokens_d = jnp.asarray(tokens[:, :width])
+            pos_d = jnp.asarray(pos)
+            lens_d = jnp.asarray(lens_main)
+        with tr.span("step_launch"):
+            out = self._step(self.params, self.caches, tokens_d, pos_d,
+                             lens_d, tables)
+        if self.mesh is not None:
+            # sharded step always returns the 3-tuple: the raw stats
+            # tree carries per-device leading (dp, tp) axes plus the MoE
+            # drop counters even when energy tracking is off
+            self.caches, logits, raw = out
+            raw_np = jax.tree.map(np.asarray, raw)
+            self.moe_dropped_tokens += self._mesh_step.moe_drops(raw_np)
+            self._accum_comms(self._mesh_step.comms_for(width))
+            if self.track_energy:
+                tree = self._mesh_step.merge_stats(raw_np)
+                step_by_bits = tree_totals_by_bits(tree)
+                self._accum_device_load(
+                    self._mesh_step.device_serial_by_bits(raw_np))
+        elif self.track_energy:
+            self.caches, logits, tree = out
+            step_by_bits = tree_totals_by_bits(tree)
+        else:
+            self.caches, logits = out
+        for b, d in step_by_bits.items():
+            acc = self.cycles_by_bits.setdefault(
+                b, {"serial_cycles": 0, "parallel_cycles": 0})
+            for k2, v2 in d.items():
+                acc[k2] += int(v2)
+        if tr.enabled:
+            with tr.span("step_wait"):
+                logits.block_until_ready()
+        with tr.span("logits_fetch"):
+            host = np.asarray(logits)
+        with tr.span("logits_widen"):
+            return np.array(host, np.float32), step_by_bits   # writable copy
+
+    def _commit(self, logits_np, scheduled, main_rows, fbset, pos, lens,
+                step_by_bits) -> None:
+        """Guard, sample and emit one plain tick's rows, under the sub-spans
+        ``logits_check`` (fault injection and the ``isfinite`` scan),
+        ``sample`` (upload, sampling, the tokens back to the host) and
+        ``emit`` (per-row bookkeeping, finish, prefix registration)."""
+        tr = self.trace
+        with tr.span("logits_check"):
+            # induced numerical faults corrupt target-policy rows only (the
+            # fallback step models the numerically-safe path)
+            if self.faults is not None:
+                for ev in self.faults.at(self.clock, "nan_logits"):
+                    r = ev.arg % self.max_batch
+                    if r in main_rows:
+                        logits_np[r] = np.nan
+            bad = [i for i in scheduled if not np.isfinite(logits_np[i]).all()]
+            for i in bad:
+                if self.slots[i].fallback:
+                    # the numerically-safe path itself is non-finite: terminal
+                    self._shed_slot(i, RejectReason.NUMERICAL_FAULT,
+                                    "non-finite logits at the fallback policy")
+                else:
+                    self._quarantine(i)
+            badset = set(bad)
+
+        with tr.span("sample"):
+            toks = np.asarray(sample(self._sample_keys(pos, lens),
+                                     jnp.asarray(logits_np), self.temperature))
+
+        with tr.span("emit"):
+            total = float(sum(int(lens[i]) for i in main_rows)) or 1.0
+            for i in scheduled:
+                sl = self.slots[i]
+                if sl is None:
+                    continue  # shed this tick (terminal numerical fault)
+                if (self.track_energy and sl.meter is not None
+                        and i not in fbset):
+                    # quarantined rows stay charged: wasted compute is real
+                    sl.meter.add_share(step_by_bits, int(lens[i]) / total)
+                if i in badset:
+                    continue  # quarantined: same position retries next tick
+                was_decoding = not sl.prefilling
+                sl.pos += int(lens[i])
+                sl.retries = 0
+                if was_decoding or not sl.prefilling:
+                    # decode rows and just-completed prefills both sampled a token
+                    self._emit(i, int(toks[i]))
+                    if len(sl.req.out) >= sl.req.max_new or sl.pos >= self.capacity - 1:
+                        self._finish(i)
+                        continue
+                self._register_prefix(i)
+            self._rr = (self._rr + 1) % self.max_batch
 
     # ------------------------------------------------------ numerical guard
     def _quarantine(self, i: int) -> None:
@@ -1778,8 +1809,14 @@ class Scheduler:
         THIS engine's construction, so co-hosted engines never see each
         other's trace events (§14 satellite fix).
 
-        ``latency`` summarizes the wall-clock histograms (seconds): TTFT
-        and inter-token percentiles over every priority class."""
+        ``compiles`` counts the backend compiles (or persistent-cache loads)
+        the process made since THIS engine's construction
+        (obs/profile.watch_compiles); a served window that adds any stalled
+        a tick on one.
+
+        ``latency`` summarizes the wall-clock histograms (seconds): TTFT,
+        inter-token, tick and queue-wait (submit to (re)admission)
+        percentiles over every priority class."""
         mgr = self.mgr
 
         def _pct(h):
@@ -1789,9 +1826,11 @@ class Scheduler:
 
         return {
             "kernels": self._kops.kernel_counters_since(self._kernel_base),
+            "compiles": compile_count() - self._compile_base,
             "latency": {"ttft_s": _pct(self._h_ttft),
                         "itl_s": _pct(self._h_itl),
-                        "tick_s": _pct(self._h_tick)},
+                        "tick_s": _pct(self._h_tick),
+                        "queue_wait_s": _pct(self._h_queue_wait)},
             "clock": self.clock,
             "ticks": self.ticks,
             "draining": self.draining,

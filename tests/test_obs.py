@@ -2,7 +2,9 @@
 health() golden keys, tracing bit-exactness (plain and speculative), kernel
 counter scoping across back-to-back schedulers, structured-log formatter."""
 
+import collections
 import dataclasses
+import glob
 import json
 
 import jax
@@ -15,6 +17,7 @@ from repro.obs.logs import kv
 from repro.obs.metrics import MetricsRegistry, family_percentile
 from repro.obs.trace import (
     NULL_TRACER,
+    PID_SCHED,
     Tracer,
     trace_summary,
     validate_chrome_trace,
@@ -83,6 +86,7 @@ def test_null_tracer_is_inert():
     assert not NULL_TRACER.enabled
     with NULL_TRACER.span("x"):  # must be a working (null) contextmanager
         pass
+    assert NULL_TRACER.span("a") is NULL_TRACER.span("b")  # one shared null
     NULL_TRACER.instant("y", 1, 0)
     NULL_TRACER.counter("z", {"a": 1})
     assert NULL_TRACER.to_dict()["traceEvents"] == []
@@ -159,10 +163,10 @@ def test_health_golden_keys(model):
     s, _ = _run(cfg, RC, params, prompts=_prompts(cfg))
     h = s.health()
     for k in ("clock", "completed", "admitted", "rejections", "ladder",
-              "kernels", "latency"):
+              "kernels", "compiles", "latency"):
         assert k in h, f"health() lost key {k!r}"
     lat = h["latency"]
-    for fam in ("ttft_s", "itl_s", "tick_s"):
+    for fam in ("ttft_s", "itl_s", "tick_s", "queue_wait_s"):
         assert set(lat[fam]) == {"count", "p50", "p95", "p99"}
         assert lat[fam]["count"] > 0
         assert lat[fam]["p50"] <= lat[fam]["p99"]
@@ -183,6 +187,81 @@ def test_kernel_counters_scoped_per_scheduler(model):
     # same workload -> same (or fewer, jit-cached) own-counts; without
     # scoping s2 would report s1's calls on top of its own
     assert total2 <= total1
+
+
+def test_compiles_scoped_per_scheduler(model):
+    """Backend compiles are process-global; health() counts only those since
+    THIS scheduler's construction, and a live tracer records each one."""
+    cfg, params = model
+    s1, _ = _run(cfg, RC, params, prompts=_prompts(cfg, n=2))
+    n1 = s1.health()["compiles"]
+    assert n1 > 0                      # its own jitted step at least
+    tr = Tracer()
+    s2 = Scheduler(cfg, RC, params, capacity=32, max_batch=3,
+                   temperature=0.0, tracer=tr)
+    assert s2.health()["compiles"] == 0
+    jax.jit(lambda x: x * 3.0 + 1.0)(np.arange(5.0))   # one new program
+    assert s2.health()["compiles"] == 1
+    assert s1.health()["compiles"] == n1 + 1
+    spans = [e for e in tr.to_dict()["traceEvents"] if e["name"] == "compile"]
+    assert len(spans) == 1 and spans[0]["dur"] > 0 and spans[0]["pid"] == PID_SCHED
+
+
+SUB_SPANS = {
+    "device_step": ["step_inputs", "step_launch", "step_wait", "logits_fetch",
+                    "logits_widen"],
+    "commit": ["logits_check", "sample", "emit"],
+}
+
+
+def test_plain_tick_sub_spans_nest_in_order(model):
+    cfg, params = model
+    tr = Tracer()
+    s, _ = _run(cfg, RC, params, prompts=_prompts(cfg), tracer=tr)
+    spans = [e for e in tr.to_dict()["traceEvents"] if e.get("ph") == "X"
+             and e["pid"] == PID_SCHED and e["name"] != "compile"]
+    eps = 1e-3  # µs: a child's recomputed end may differ by an ulp
+
+    def inside(p):
+        return sorted((e for e in spans if e is not p
+                       and p["ts"] <= e["ts"]
+                       and e["ts"] + e["dur"] <= p["ts"] + p["dur"] + eps),
+                      key=lambda e: e["ts"])
+
+    for parent, subs in SUB_SPANS.items():
+        ps = [e for e in spans if e["name"] == parent]
+        assert len(ps) == s.ticks > 0
+        for p in ps:
+            assert [e["name"] for e in inside(p)] == subs, parent
+    for t in (e for e in spans if e["name"] == "tick"):
+        names = [e["name"] for e in inside(t)]
+        if "device_step" in names:     # a tick that ran the step
+            top = [n for n in names if not any(n in v for v in SUB_SPANS.values())]
+            assert top == ["admit", "plan", "cow_drain", "tables",
+                           "device_step", "commit"]
+
+
+def test_phases_land_on_the_profilers_host_line(model, tmp_path):
+    """Under a jax.profiler capture every scheduler-track span is also a
+    serve/<name> annotation on the host line: one serve/device_step a step."""
+    from jax.profiler import ProfileData
+
+    cfg, params = model
+    s = Scheduler(cfg, RC, params, capacity=32, max_batch=3, temperature=0.0,
+                  tracer=Tracer())
+    for rid, p in enumerate(_prompts(cfg, n=2)):
+        s.submit(Request(rid=rid, prompt=p, max_new=3))
+    with jax.profiler.trace(str(tmp_path)):
+        s.run()
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(path)
+    names = collections.Counter(
+        e.name for plane in pd.planes if plane.name.startswith("/host")
+        for ln in plane.lines for e in ln.events if e.name.startswith("serve/"))
+    assert names["serve/device_step"] == s.ticks > 0
+    assert names["serve/tick"] == s.clock
+    for n in SUB_SPANS["device_step"] + SUB_SPANS["commit"]:
+        assert names[f"serve/{n}"] == s.ticks, n
 
 
 def test_tracing_changes_no_tokens_plain(model):
