@@ -313,9 +313,11 @@ def bench_roofline_gate(fast: bool, out: dict, iters: int = 10) -> None:
     kv, group, hd, bs, MB, B = (2, 2, 32, 8, 4, 4) if fast else (4, 4, 64, 16, 8, 8)
     P = B * MB
     kq, ks = _quantize_kv(jnp.asarray(
-        rng.standard_normal((P + 1, bs, kv, hd)).astype(np.float32)))
+        rng.standard_normal((P + 1, bs, kv * hd)).astype(np.float32)))
     vq, vs = _quantize_kv(jnp.asarray(
-        rng.standard_normal((P + 1, bs, kv, hd)).astype(np.float32)))
+        rng.standard_normal((P + 1, bs, kv * hd)).astype(np.float32)))
+    # a stack of one layer, as the cache stores it: (1, P+1, bs, kv*hd)
+    kq, ks, vq, vs = (a[None] for a in (kq, ks, vq, vs))
     tables = jnp.arange(P, dtype=jnp.int32).reshape(B, MB)
     pos = jnp.full((B,), MB * bs - 1, jnp.int32)   # full rows, decode step
     lens = jnp.ones((B,), jnp.int32)
@@ -323,13 +325,13 @@ def bench_roofline_gate(fast: bool, out: dict, iters: int = 10) -> None:
         rng.standard_normal((B, 1, kv * group, hd)).astype(np.float32))
 
     def step(q, kq, ks, vq, vs, tables, pos, lens):
-        view = KVView(pos, lens, tables, block_size=bs, layout="paged")
+        view = KVView(pos, lens, tables, block_size=bs, layout="paged", layer=0)
         cache = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
         o = paged_decode_attention(q, cache, ("k",), "v", view,
                                    kv_heads=kv, name="roofline.paged")
         if o is None:  # CPU: the XLA twin is the path serving actually runs
-            kf = kv_cache_read(cache, "k", q.dtype, kv_len=view.kv_len, view=view)
-            vf = kv_cache_read(cache, "v", q.dtype, kv_len=view.kv_len, view=view)
+            kf, vf = (kv_cache_read(cache, n, q.dtype, kv_len=view.kv_len, view=view)
+                      .reshape(B, -1, kv, hd) for n in ("k", "v"))
             o = blockwise_attention(q, kf, vf, q_offset=view.pos,
                                     kv_len=view.kv_len, causal=True)
         return o

@@ -119,26 +119,29 @@ def kernel_phase(cfg, pallas: str) -> list[str]:
             errs.append(f"k: {label} differs from its XLA twin")
 
     kv, hd, heads, bs, pages = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_heads, 16, 128
+    layers = 2   # a layer-stacked pool, read at its last layer
     tables = jnp.asarray(r.permutation(pages).reshape(4, pages // 4), jnp.int32)
     for int8 in (False, True):
         cache = {}
         for n in ("k", "v"):
-            data = jnp.asarray(r.standard_normal((pages + 1, bs, kv, hd)), bf16)
+            data = jnp.asarray(
+                r.standard_normal((layers * (pages + 1), bs, kv * hd)), bf16)
             if int8:
-                cache[n], cache[n + "_scale"] = _quantize_kv(data)
+                q8, s8 = _quantize_kv(data)
+                cache[n] = q8.reshape(layers, pages + 1, bs, kv * hd)
+                cache[n + "_scale"] = s8.reshape(layers, pages + 1, bs)
             else:
-                cache[n] = data
+                cache[n] = data.reshape(layers, pages + 1, bs, kv * hd)
         for sq, pos in ((1, [0, 37, 300, 510]), (CHUNK, [0, 128, 200, 384])):
             view = KVView(jnp.asarray(pos, jnp.int32), jnp.full((4,), sq, jnp.int32),
-                          tables, block_size=bs, layout="paged")
+                          tables, block_size=bs, layout="paged", layer=layers - 1)
             q = jnp.asarray(r.standard_normal((4, sq, heads, hd)), bf16)
             out = flash_paged_decode(
-                q, (cache["k"].reshape(pages + 1, bs, kv * hd),),
-                (cache.get("k_scale"),), cache["v"].reshape(pages + 1, bs, kv * hd),
-                cache.get("v_scale"), view.tables, view.pos, view.kv_len,
+                q, (cache["k"],), (cache.get("k_scale"),), cache["v"],
+                cache.get("v_scale"), view.tables, view.pos, view.kv_len, view.layer,
                 kv_heads=kv, interpret=pallas == "pallas_interpret")
             k_full, v_full = (kv_cache_read(cache, n, bf16, kv_len=view.kv_len, view=view)
-                              for n in ("k", "v"))
+                              .reshape(4, -1, kv, hd) for n in ("k", "v"))
             ref = blockwise_attention(q, k_full, v_full, q_offset=view.pos,
                                       kv_len=view.kv_len)
             o, t = np.asarray(out, np.float32), np.asarray(ref, np.float32)

@@ -9,9 +9,12 @@ the int8 pool before a single score is computed. This kernel fuses the whole
 decode read side instead:
 
 * grid = (batch, max_blocks) — split-K over the per-row block table. The
-  page index for grid step (b, m) is ``tables[b, m]``, wired through a
-  scalar-prefetch index map (``pltpu.PrefetchScalarGridSpec``), so each page
-  streams HBM→VMEM exactly once and the gathered intermediate never exists.
+  kernel takes the whole layer-stacked pool ``(layers, pages+1, bs, F)``
+  and a layer index; the block for grid step (b, m) is
+  ``(layer, tables[b, m])``, wired through a scalar-prefetch index map
+  (``pltpu.PrefetchScalarGridSpec``), so each page streams HBM→VMEM exactly
+  once, and neither the gathered intermediate nor a slice of one layer's
+  pool ever exists.
 * int8 KV dequant happens in-register per page (``int8 * scale[token]``,
   the same float ops as the XLA twin's pool dequant), fused into the
   attention inner loop.
@@ -23,11 +26,13 @@ decode read side instead:
 
 Operand model (covers both attention families):
 
-* GQA: one K part ``(pages+1, bs, kv*hd)`` and V ``(pages+1, bs, kv*hd)``;
-  query heads are kv-major (head h reads kv head h // n_rep), so the
-  per-kv-head feature slices line up with contiguous query-row blocks.
+* GQA: one K part ``(layers, pages+1, bs, kv*hd)`` and V of the same
+  shape, head-major as the cache stores them; query heads are kv-major
+  (head h reads kv head h // n_rep), so the per-kv-head feature slices line
+  up with contiguous query-row blocks.
 * MLA (absorbed decode): two K parts — the compressed latent
-  ``(pages+1, bs, lora)`` and the rope keys ``(pages+1, bs, rope_d)`` —
+  ``(layers, pages+1, bs, lora)`` and the rope keys
+  ``(layers, pages+1, bs, rope_d)`` —
   concatenated per page in-register (dot over a concat == sum of dots, but
   concatenating first keeps the float accumulation order identical to the
   XLA twin's ``concat([ckv, kr])``); V is the latent part.
@@ -54,6 +59,7 @@ __all__ = [
 ]
 
 NEG_INF = -1e30  # models/flash.py's mask value (finite: exp() underflows to 0)
+SCALE_ROWS = 8   # pages of int8 scales per block: one f32 sublane tile
 
 # ------------------------------------------------------------ impl selection
 # Mirrors kernels/ops.py ``_resolve`` but module-scoped: the paged decode
@@ -85,24 +91,26 @@ def paged_impl() -> tuple[str, bool]:
     return "xla", False
 
 
-def _deq(ref, scale_ref):
-    """One page (1, bs, F) in storage dtype → (bs, F) f32, dequantized.
+def _deq(ref, scale_ref, row):
+    """One page (1, 1, bs, F) in storage dtype → (bs, F) f32, dequantized.
 
     Same float op as the XLA twin's pool read: ``int8 → f32 * scale[token]``
-    with the per-token scale — a (1, bs, 1) block, so the (bs, 1) column
-    broadcasts over every feature lane — applied to every feature."""
-    page = ref[0]
+    with the per-token scale — row ``row`` of the (1, SCALE_ROWS, bs) block
+    of page scales, turned into a (bs, 1) column that broadcasts over every
+    feature lane — applied to every feature."""
+    page = ref[0, 0]
     if page.dtype == jnp.int8:
-        return page.astype(jnp.float32) * scale_ref[0]
+        scale = scale_ref[0, pl.ds(row, 1), :]               # (1, bs)
+        return page.astype(jnp.float32) * jnp.transpose(scale)
     return page.astype(jnp.float32)
 
 
 def _kernel(
     # scalar prefetch
-    tables_ref, pos_ref, len_ref,
+    tables_ref, pos_ref, len_ref, _layer_ref,
     # tensor operands: q, then per K part (pool [+ scale]), then v [+ scale]
     *refs,
-    n_pages, bs, kv, group, sq, part_dims, hdv,
+    n_pages, bs, kv, group, sq, part_dims, hdv, scale_rows,
     causal, window, k_int8, v_int8,
 ):
     it = iter(refs)
@@ -138,8 +146,9 @@ def _kernel(
 
     # dequantized page: K parts concatenated on features (MLA [ckv ; kr]),
     # V taken whole — each laid out (bs, kv * per-head-features)
-    parts = [_deq(r, s) for r, s in zip(k_refs, ks_refs)]
-    v_page = _deq(v_ref, vs_ref)
+    row = tables_ref[b, m] % scale_rows      # this page's row of its scale block
+    parts = [_deq(r, s, row) for r, s in zip(k_refs, ks_refs)]
+    v_page = _deq(v_ref, vs_ref, row)
 
     # scores per kv head: q rows [g*group*sq, (g+1)*group*sq) dot that head's
     # feature slice of every part
@@ -193,13 +202,14 @@ def _kernel(
 )
 def flash_paged_decode(
     q: jnp.ndarray,                    # (B, Sq, H, hd_tot) — Sq = step width
-    k_parts: tuple,                    # pools (P+1, bs, kv*f_i) — concat = K
-    k_scales: tuple,                   # per part: (P+1, bs) f32 or None
-    v_pool: jnp.ndarray,               # (P+1, bs, kv*hdv)
-    v_scale: jnp.ndarray | None,       # (P+1, bs) f32 or None
+    k_parts: tuple,                    # pools (L, P+1, bs, kv*f_i) — concat = K
+    k_scales: tuple,                   # per part: (L, P+1, bs) f32 or None
+    v_pool: jnp.ndarray,               # (L, P+1, bs, kv*hdv)
+    v_scale: jnp.ndarray | None,       # (L, P+1, bs) f32 or None
     tables: jnp.ndarray,               # (B, MB) int32 page ids
     pos: jnp.ndarray,                  # (B,) int32 — absolute position of q[:, 0]
     kv_len: jnp.ndarray,               # (B,) int32 — valid tokens per row
+    layer,                             # int32 scalar — the pools' layer to read
     *,
     kv_heads: int,
     causal: bool = True,
@@ -212,14 +222,15 @@ def flash_paged_decode(
     concatenated K parts; query heads are kv-major (h // n_rep selects the
     kv head, matching models/flash.py ``_repeat_kv``). Scores are scaled by
     ``1 / sqrt(hd_tot)`` exactly like ``blockwise_attention``. int8 pools
-    carry a per-(page, token) f32 scale; float pools pass scale=None."""
+    carry a per-(page, token) f32 scale; float pools pass scale=None. Every
+    pool is the whole layer stack; only layer ``layer``'s pages are read."""
     B, sq, H, hd_tot = q.shape
     kv = kv_heads
     group = H // kv
-    n_rows, bs = v_pool.shape[0], v_pool.shape[1]
+    bs = v_pool.shape[2]
     n_pages = tables.shape[1]
-    part_dims = tuple(p.shape[2] // kv for p in k_parts)
-    hdv = v_pool.shape[2] // kv
+    part_dims = tuple(p.shape[3] // kv for p in k_parts)
+    hdv = v_pool.shape[3] // kv
     assert sum(part_dims) == hd_tot, (part_dims, hd_tot)
     assert H == kv * group, (q.shape, kv)
     hq = kv * group * sq
@@ -233,28 +244,34 @@ def flash_paged_decode(
     k_int8 = k_parts[0].dtype == jnp.int8
     v_int8 = v_pool.dtype == jnp.int8
 
-    def page_map(b, m, tbl, _pos, _len):
-        return (tbl[b, m], 0, 0)
+    def page_map(b, m, tbl, _pos, _len, lyr):
+        return (lyr[0], tbl[b, m], 0, 0)
+
+    # int8 page scales (L, P+1, bs) are read SCALE_ROWS pages at a time: a
+    # (1, 1, bs) block is refused by Mosaic (its second-minor dim is neither
+    # a multiple of 8 nor the whole axis), and the scales are stored in the
+    # layout the cache writes them in, so no relayout of the plane is made
+    scale_rows = min(SCALE_ROWS, v_pool.shape[1])
+
+    def scale_map(b, m, tbl, _pos, _len, lyr):
+        return (lyr[0], tbl[b, m] // scale_rows, 0)
 
     in_specs = [pl.BlockSpec((1, hq, hd_tot), lambda b, m, *_: (b, 0, 0))]
     operands: list = [qf]
-    # int8 page scales ride as (P+1, bs, 1) columns: a (1, bs, 1) block spans
-    # the array's last two dims, which Mosaic accepts where a (1, bs) row
-    # block over (P+1, bs) is refused, and lands in VMEM already (bs, 1)
     for part, scale in zip(k_parts, k_scales):
-        in_specs.append(pl.BlockSpec((1, bs, part.shape[2]), page_map))
+        in_specs.append(pl.BlockSpec((1, 1, bs, part.shape[3]), page_map))
         operands.append(part)
         if k_int8:
-            in_specs.append(pl.BlockSpec((1, bs, 1), page_map))
-            operands.append(scale[..., None])
-    in_specs.append(pl.BlockSpec((1, bs, v_pool.shape[2]), page_map))
+            in_specs.append(pl.BlockSpec((1, scale_rows, bs), scale_map))
+            operands.append(scale)
+    in_specs.append(pl.BlockSpec((1, 1, bs, v_pool.shape[3]), page_map))
     operands.append(v_pool)
     if v_int8:
-        in_specs.append(pl.BlockSpec((1, bs, 1), page_map))
-        operands.append(v_scale[..., None])
+        in_specs.append(pl.BlockSpec((1, scale_rows, bs), scale_map))
+        operands.append(v_scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hq, hdv), lambda b, m, *_: (b, 0, 0)),
@@ -268,14 +285,15 @@ def flash_paged_decode(
         functools.partial(
             _kernel,
             n_pages=n_pages, bs=bs, kv=kv, group=group, sq=sq,
-            part_dims=part_dims, hdv=hdv, causal=causal, window=window,
+            part_dims=part_dims, hdv=hdv, scale_rows=scale_rows,
+            causal=causal, window=window,
             k_int8=k_int8, v_int8=v_int8,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hq, hdv), jnp.float32),
         interpret=interpret,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), kv_len.astype(jnp.int32),
-      *operands)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
     # (B, hq, hdv) → (B, kv, n_rep, Sq, hdv) → (B, Sq, H, hdv)
     out = out.reshape(B, kv, group, sq, hdv).reshape(B, H, sq, hdv)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)

@@ -7,7 +7,17 @@ Decode uses a pre-allocated KV cache in one of two layouts:
 - **paged**: a fixed pool of ``block_size``-token pages shared by all slots,
   addressed through per-slot block tables (a :class:`KVView`) — per-row write
   positions/lengths, so one jitted step can mix prefill chunks and decode
-  rows (serve/scheduler.py) and cache memory scales with live tokens.
+  rows (serve/scheduler.py) and cache memory scales with live tokens. A GQA
+  pool stores each token's heads head-major on one feature axis,
+  ``(pages+1, block_size, kv*hd)``: the layout the paged kernel reads.
+
+The attention functions take a layer group's caches *stacked* along a
+leading layers axis, and the index of the layer to use (``KVView.layer``,
+or ``layer`` on the legacy path, which has no view): writes scatter the
+step's tokens into the stack in place at ``[layer, ...]`` and reads gather
+only the pages (or rows) they need, so a step never copies a whole layer of
+the paged pool (models/transformer.py carries the stack through the layer
+loop).
 
 MLA caches the *compressed* kv latent and decodes in the absorbed form (no
 decompression — the production DeepSeek serving path). KV caches optionally
@@ -19,6 +29,7 @@ reuse never leaks a previous occupant's stale pages/rows into the view.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 
 import jax
@@ -51,21 +62,24 @@ class KVView:
     sequence), ``lens[b]`` how many of the step's S columns are real tokens
     (0 = row idle this tick; its writes are dropped and its outputs unread).
     ``tables[b]`` maps block index -> page id in the pooled cache for the
-    paged layout (None = dense per-row addressing). ``block_size`` and
-    ``layout`` are static (trace-time) attributes."""
+    paged layout (None = dense per-row addressing). ``layer`` is the index,
+    into a layer group's stacked cache buffers, of the layer being run (the
+    model's layer loop sets it per layer). ``block_size`` and ``layout``
+    are static (trace-time) attributes."""
 
     pos: jnp.ndarray                  # (B,) int32
     lens: jnp.ndarray                 # (B,) int32
     tables: jnp.ndarray | None = None  # (B, max_blocks) int32 page ids
     block_size: int = 16
     layout: str = "dense"             # dense | paged
+    layer: jnp.ndarray | int | None = None  # int32 scalar layer index
 
     def tree_flatten(self):
-        return (self.pos, self.lens, self.tables), (self.block_size, self.layout)
+        return (self.pos, self.lens, self.tables, self.layer), (self.block_size, self.layout)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], children[2], aux[0], aux[1])
+        return cls(*children[:3], *aux, children[3])
 
     @property
     def kv_len(self) -> jnp.ndarray:
@@ -81,8 +95,15 @@ jax.tree_util.register_pytree_node(
 def paged_view_capacity(view: KVView) -> int:
     """Token capacity of the contiguous per-row view a block table spans."""
     return view.tables.shape[1] * view.block_size
-def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype) -> dict:
-    """Per-layer attention cache (unstacked; caller stacks per layer group)."""
+def init_kv_cache(
+    cfg: ModelConfig, batch: int, capacity: int, dtype, *, paged: bool = False
+) -> dict:
+    """Per-layer attention cache (unstacked; caller stacks per layer group).
+
+    Dense: (batch, capacity, kv, hd) k/v. ``paged`` (batch = pages+1,
+    capacity = block_size): k/v (pages+1, bs, kv*hd), head-major, the
+    layout the paged kernel reads. MLA latents are (rows, tokens, f) and
+    int8 token scales (rows, tokens) either way."""
     hd = cfg.resolved_head_dim
     if cfg.attn_type == "mla":
         cache = {
@@ -91,9 +112,10 @@ def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int, dtype) -> dict:
         }
     else:
         kv = cfg.num_kv_heads
+        feat = (kv * hd,) if paged else (kv, hd)
         cache = {
-            "k": jnp.zeros((batch, capacity, kv, hd), dtype),
-            "v": jnp.zeros((batch, capacity, kv, hd), dtype),
+            "k": jnp.zeros((batch, capacity) + feat, dtype),
+            "v": jnp.zeros((batch, capacity) + feat, dtype),
         }
     if dtype == jnp.int8:
         for n in list(cache):
@@ -145,8 +167,11 @@ def _paged_targets(view: KVView, B: int, S: int, num_rows: int):
     return page, tp % bs
 
 
-def _write_one(cache: dict, out: dict, name: str, val, pos, view: KVView | None):
-    """Write ``val`` (B, S, ...) into one cache buffer (plus its scale)."""
+def _write_one(
+    cache: dict, out: dict, name: str, val, pos, view: KVView | None, layer
+):
+    """Write ``val`` (B, S, ...) into one layer of one stacked cache buffer
+    (plus its scale), in place: ``view.layer``, or ``layer`` with no view."""
     from ..parallel import collectives as dist  # trace-time mesh program
 
     prog = dist.current_program()
@@ -170,25 +195,31 @@ def _write_one(cache: dict, out: dict, name: str, val, pos, view: KVView | None)
         # rows — already quantized, so int8 planes on the wire — and address
         # through the full-batch write view
         vals = [(n, prog.gather_rows_dp(v, f"kv.{n}")) for n, v in vals]
-        view = prog.write_view
+        view = dataclasses.replace(prog.write_view, layer=view.layer)
     B, S = vals[0][1].shape[:2]
+    if view is not None:
+        layer = view.layer
     for n, v in vals:
-        dst = cache[n]
+        dst = cache[n]                                     # (L, ...)
         if view is None:
-            out[n] = jax.lax.dynamic_update_slice_in_dim(dst, v, pos, axis=1)
+            start = (layer, 0, pos) + (0,) * (dst.ndim - 3)
+            out[n] = jax.lax.dynamic_update_slice(dst, v[None], start)
         elif view.tables is None:  # dense layout, per-row positions
-            rows, tp = _scatter_targets(view, B, S, dst.shape[1])
-            out[n] = dst.at[rows, tp].set(v, mode="drop")
-        else:                      # paged pool: (pages+1, block_size, ...)
-            page, off = _paged_targets(view, B, S, dst.shape[0])
-            out[n] = dst.at[page, off].set(v, mode="drop")
+            rows, tp = _scatter_targets(view, B, S, dst.shape[2])
+            out[n] = dst.at[layer, rows, tp].set(v, mode="drop")
+        else:                      # paged pool: (L, pages+1, block_size[, F])
+            page, off = _paged_targets(view, B, S, dst.shape[1])
+            v = v.reshape((B, S) + dst.shape[3:])          # heads -> features
+            out[n] = dst.at[layer, page, off].set(v, mode="drop")
     return out
 
 
 def kv_cache_write(
-    cache: dict, names: tuple[str, str], new: tuple, pos, *, view: KVView | None = None
+    cache: dict, names: tuple[str, str], new: tuple, pos, *,
+    view: KVView | None = None, layer=None,
 ) -> dict:
-    """Write a (B, S, ...) span of k/v tokens.
+    """Write a (B, S, ...) span of k/v tokens into one layer of the stacked
+    cache buffers: ``view.layer``, or ``layer`` on the legacy path.
 
     Legacy path (``view=None``): all rows share the scalar write position
     ``pos`` (dynamic_update_slice over a static-capacity buffer). With a
@@ -197,7 +228,7 @@ def kv_cache_write(
     into the page pool; padded columns are dropped."""
     out = dict(cache)
     for name, val in zip(names, new):
-        out = _write_one(cache, out, name, val, pos, view)
+        out = _write_one(cache, out, name, val, pos, view, layer)
     return out
 
 
@@ -218,29 +249,32 @@ def kv_cache_read(
     *,
     kv_len=None,
     view: KVView | None = None,
+    layer=None,
 ) -> jnp.ndarray:
-    """Materialize one cache buffer as a contiguous (B, capacity, ...) view.
+    """Materialize one layer of one stacked cache buffer (``view.layer``, or
+    ``layer`` with no view) as a contiguous (B, capacity, ...) view.
 
     ``kv_len`` (scalar or per-row (B,)) length-masks the result: dead
     positions come back as exact zeros, so the int8 dequant never exposes a
     previous occupant's stale rows/pages and a fresh page needs no zeroing.
-    With a paged :class:`KVView`, pages are gathered through the block table
-    into a contiguous view of ``max_blocks * block_size`` tokens per row."""
+    With a paged :class:`KVView`, that layer's pages are gathered through
+    the block table into a contiguous view of ``max_blocks * block_size``
+    tokens per row, (B, capacity, F) with F the pool's feature axis."""
+    if view is not None:
+        layer = view.layer
     if view is not None and view.tables is not None:
-        pool = cache[name]                                  # (P+1, bs, ...)
+        pool = cache[name]                                  # (L, P+1, bs, F)
         B = view.tables.shape[0]
-        gathered = pool[view.tables]                        # (B, MB, bs, ...)
-        buf = gathered.reshape((B, paged_view_capacity(view)) + pool.shape[2:])
+        cap = paged_view_capacity(view)
+        buf = pool[layer, view.tables].reshape((B, cap) + pool.shape[3:])
         if pool.dtype == jnp.int8:
-            s = cache[name + "_scale"][view.tables].reshape(
-                B, paged_view_capacity(view)
-            )
+            s = cache[name + "_scale"][layer, view.tables].reshape(B, cap)
             deq = buf.astype(jnp.float32) * s.reshape(s.shape + (1,) * (buf.ndim - 2))
             return _mask_dead(deq, kv_len).astype(compute_dtype)
         return _mask_dead(buf, kv_len).astype(compute_dtype)
-    buf = cache[name]
+    buf = cache[name][layer]
     if buf.dtype == jnp.int8:
-        s = cache[name + "_scale"]
+        s = cache[name + "_scale"][layer]
         deq = buf.astype(jnp.float32) * s.reshape(s.shape + (1,) * (buf.ndim - 2))
         return _mask_dead(deq, kv_len).astype(compute_dtype)
     return _mask_dead(buf, kv_len).astype(compute_dtype)
@@ -268,7 +302,8 @@ def gqa_attention(
     positions: jnp.ndarray,         # (B, S) or (3, B, S) for M-RoPE
     *,
     backend: GemmBackend,
-    cache: dict | None = None,
+    cache: dict | None = None,      # the layer group's stacked k/v buffers
+    layer=None,                     # index into ``cache`` with no kv_view
     cache_pos=None,                 # scalar write position (decode)
     kv_view: KVView | None = None,  # per-row addressing (mixed steps / paged)
     is_global: bool = True,         # False -> sliding window
@@ -316,13 +351,16 @@ def gqa_attention(
                 v_full = kv_cache_read(
                     cache, "v", x.dtype, kv_len=kv_len, view=kv_view)
         else:
-            cache = kv_cache_write(cache, ("k", "v"), (k, v), cache_pos)
-            capacity = cache["k"].shape[1]
+            cache = kv_cache_write(cache, ("k", "v"), (k, v), cache_pos, layer=layer)
+            capacity = cache["k"].shape[2]
             kv_len = jnp.minimum(jnp.asarray(cache_pos, jnp.int32) + S, capacity)
-            k_full = kv_cache_read(cache, "k", x.dtype, kv_len=kv_len)
-            v_full = kv_cache_read(cache, "v", x.dtype, kv_len=kv_len)
+            k_full = kv_cache_read(cache, "k", x.dtype, layer=layer, kv_len=kv_len)
+            v_full = kv_cache_read(cache, "v", x.dtype, layer=layer, kv_len=kv_len)
             q_offset = cache_pos
         if out is None:
+            # a paged read is (B, cap, kv*hd); this shard's heads back apart
+            k_full = k_full.reshape(k_full.shape[:2] + k.shape[2:])
+            v_full = v_full.reshape(v_full.shape[:2] + v.shape[2:])
             out = blockwise_attention(
                 q,
                 k_full,
@@ -366,6 +404,7 @@ def mla_attention(
     *,
     backend: GemmBackend,
     cache: dict | None = None,
+    layer=None,
     cache_pos=None,
     kv_view: KVView | None = None,
     chunk: int = 1024,
@@ -415,13 +454,13 @@ def mla_attention(
         kr_full = kv_cache_read(cache, "kr", x.dtype, kv_len=kv_len, view=kv_view)
     elif cache is not None:
         cache = kv_cache_write(
-            cache, ("ckv", "kr"), (ckv, k_rope), cache_pos
+            cache, ("ckv", "kr"), (ckv, k_rope), cache_pos, layer=layer
         )
         kv_len = jnp.minimum(
-            jnp.asarray(cache_pos, jnp.int32) + S, cache["ckv"].shape[1]
+            jnp.asarray(cache_pos, jnp.int32) + S, cache["ckv"].shape[2]
         )
-        ckv_full = kv_cache_read(cache, "ckv", x.dtype, kv_len=kv_len)
-        kr_full = kv_cache_read(cache, "kr", x.dtype, kv_len=kv_len)
+        ckv_full = kv_cache_read(cache, "ckv", x.dtype, layer=layer, kv_len=kv_len)
+        kr_full = kv_cache_read(cache, "kr", x.dtype, layer=layer, kv_len=kv_len)
         q_offset = cache_pos
     else:
         ckv_full, kr_full, kv_len, q_offset = ckv, k_rope, None, 0

@@ -275,10 +275,10 @@ def blockwise_attention(
 
 def paged_decode_attention(
     q: jnp.ndarray,            # (B, Sq, H, hd_tot), pre-scaled by caller if MLA
-    cache: dict,               # paged pool buffers (pages+1, block_size, ...)
+    cache: dict,               # layer-stacked paged pools (L, pages+1, block_size, F)
     k_names: tuple[str, ...],  # pool names whose feature concat forms K
     v_name: str,               # pool name read as V
-    view,                      # KVView with tables (paged layout)
+    view,                      # KVView with tables (paged layout) and layer
     *,
     kv_heads: int,
     causal: bool = True,
@@ -294,6 +294,10 @@ def paged_decode_attention(
     pallas (kernels.flash_paged.paged_impl: auto = TPU, or forced via
     ``REPRO_PAGED_ATTN`` / set_paged_impl) and the step is not running a
     sharded mesh program (the gather path owns the collective choreography).
+
+    The kernel reads the whole layer stack in the layout the cache stores
+    it, by layer index, so no slice or relayout of a layer's pool is made;
+    the trace-time counter ``kv.pool: in_place`` records that it did.
     """
     from ..kernels import ops
     from ..kernels.flash_paged import flash_paged_decode, paged_impl
@@ -307,21 +311,18 @@ def paged_decode_attention(
         ops.record_fallback(name, "mesh")
         return None
     int8 = cache[k_names[0]].dtype == jnp.int8
-
-    def pool3(n):  # (P+1, bs, kv, hd) and (P+1, bs, f) both -> (P+1, bs, kv*f)
-        p = cache[n]
-        return p.reshape(p.shape[0], p.shape[1], -1)
-
     ops.record_path(name, "pallas")
+    ops.record_path("kv.pool", "in_place")
     return flash_paged_decode(
         q,
-        tuple(pool3(n) for n in k_names),
+        tuple(cache[n] for n in k_names),
         tuple(cache[n + "_scale"] if int8 else None for n in k_names),
-        pool3(v_name),
+        cache[v_name],
         cache[v_name + "_scale"] if int8 else None,
         view.tables,
         view.pos,
         view.kv_len,
+        view.layer,
         kv_heads=kv_heads,
         causal=causal,
         window=window,

@@ -8,7 +8,10 @@ layer kinds), not O(num_layers) — required for the 48-layer 400B config on a
 periodic pattern (llama4's dense/MoE alternation scans as 24 two-block
 super-layers) or falls back to contiguous uniform segments (hymba's three
 full-attention layers split the SWA stack). Params and caches for a group are
-stacked along a leading ``layers`` axis and driven by ``lax.scan``.
+stacked along a leading ``layers`` axis and driven by ``lax.scan``: params
+as the scan's xs, caches in its carry, so each layer writes and reads its
+cache in place at its layer index (the scan counter) and a step never
+slices out or copies a whole layer of the cache.
 
 Block layouts (pre-norm, residual):
 - dense/MoE:  x += attn(norm(x));  x += mlp|moe(norm(x))
@@ -19,6 +22,7 @@ Block layouts (pre-norm, residual):
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 
 import jax
@@ -172,11 +176,11 @@ def _block_cache(
     cache: dict = {}
     if kind.mixer in ("gqa", "mla", "hybrid"):
         if paged_pool is not None:
-            # the paged KV pool reuses the dense leaf layout with
-            # batch -> pages (+1 trash page for dropped writes) and
-            # capacity -> block_size; one block table addresses every layer
+            # the paged KV pool: batch -> pages (+1 trash page for dropped
+            # writes), capacity -> block_size, in the layout the paged
+            # kernel reads; one block table addresses every layer
             pages, bs = paged_pool
-            cache.update(init_kv_cache(cfg, pages + 1, bs, kv_dtype))
+            cache.update(init_kv_cache(cfg, pages + 1, bs, kv_dtype, paged=True))
         else:
             cache.update(init_kv_cache(cfg, batch, capacity, kv_dtype))
     if kind.mixer in ("ssm", "hybrid"):
@@ -196,7 +200,8 @@ def init_caches(
 
     ``rc.kv_layout="dense"``: KV leaves are (layers, batch, capacity, ...).
     ``rc.kv_layout="paged"``: KV leaves become page pools
-    (layers, num_pages+1, block_size, ...) shared by all slots and indexed
+    (layers, num_pages+1, block_size, features) shared by all slots — GQA
+    k/v hold kv*hd head-major features, int8 scales one — and indexed
     through a block table (models.attention.KVView); the trailing trash page
     swallows masked writes. SSM state stays dense per slot (no seq axis).
     ``num_pages`` defaults to the dense equivalent batch*ceil(cap/bs)."""
@@ -230,26 +235,29 @@ def _apply_block(
     *,
     backend: GemmBackend,
     cache: dict | None,
+    layer,
     cache_pos,
     kv_view: KVView | None,
     chunk: int,
     want_state: bool,
 ):
-    """One block. Returns (x, new_cache|None, aux, stats|None) — stats is the
-    block's drained capture frame ({gemm name: CapturedGemm}) when a stats
-    capture is active, so the per-layer tuGEMM cycle counts travel through
-    jax.checkpoint / lax.scan as ordinary traced outputs."""
+    """One block. ``cache`` is the block's layer-stacked cache and ``layer``
+    this block's index into it. Returns (x, new_cache|None, aux,
+    stats|None): new_cache is the whole stack, updated at ``layer``; stats
+    is the block's drained capture frame ({gemm name: CapturedGemm}) when a
+    stats capture is active, so the per-layer tuGEMM cycle counts travel
+    through jax.checkpoint / lax.scan as ordinary traced outputs."""
     if stats_capture.capturing():
         with stats_capture.frame() as fr:
             x, new_cache, aux, _ = _apply_block_inner(
                 cfg, kind, p, x, positions, backend=backend, cache=cache,
-                cache_pos=cache_pos, kv_view=kv_view, chunk=chunk,
+                layer=layer, cache_pos=cache_pos, kv_view=kv_view, chunk=chunk,
                 want_state=want_state,
             )
         return x, new_cache, aux, stats_capture.as_tree(fr)
     return _apply_block_inner(
         cfg, kind, p, x, positions, backend=backend, cache=cache,
-        cache_pos=cache_pos, kv_view=kv_view, chunk=chunk,
+        layer=layer, cache_pos=cache_pos, kv_view=kv_view, chunk=chunk,
         want_state=want_state,
     )
 
@@ -263,6 +271,7 @@ def _apply_block_inner(
     *,
     backend: GemmBackend,
     cache: dict | None,
+    layer,
     cache_pos,
     kv_view: KVView | None,
     chunk: int,
@@ -279,7 +288,7 @@ def _apply_block_inner(
             kv_cache = {k: v for k, v in cache.items() if k not in ("h", "conv")}
         y_attn, kv_out = attn_fn(
             cfg, p["attn"], h, positions,
-            backend=backend, cache=kv_cache, cache_pos=cache_pos,
+            backend=backend, cache=kv_cache, layer=layer, cache_pos=cache_pos,
             kv_view=kv_view, is_global=kind.is_global, chunk=chunk,
         )
         if kv_out is not None:
@@ -287,12 +296,12 @@ def _apply_block_inner(
 
     if kind.mixer == "ssm" or kind.mixer == "hybrid":
         if cache is not None and "h" in cache:
-            ssm_state = {"h": cache["h"], "conv": cache["conv"]}
+            ssm_state = {"h": cache["h"][layer], "conv": cache["conv"][layer]}
             if x.shape[1] == 1:
                 y_ssm, st = mamba_decode_step(cfg, p["ssm"], h, ssm_state, backend=backend)
             else:
                 y_ssm, st = mamba_mixer(cfg, p["ssm"], h, backend=backend, return_state=True)
-            new_cache.update(st)
+            new_cache.update({n: cache[n].at[layer].set(v) for n, v in st.items()})
         else:
             y_ssm, st = mamba_mixer(
                 cfg, p["ssm"], h, backend=backend, return_state=want_state
@@ -378,18 +387,19 @@ def forward(
     new_caches = []
     stats_groups = []  # per-group stats trees, stacked along the layers axis
 
-    def superblock(kinds, x, p, cache):
+    def superblock(kinds, x, p, cache, layer):
         # residual stream layout anchor (seq-sharded under SP overrides)
         x = constrain(x, "batch", "seq", "act_embed")
         aux = jnp.zeros((), jnp.float32)
         ncache = {}
         sdict = {}
+        view = None if kv_view is None else dataclasses.replace(kv_view, layer=layer)
         for j, kind in enumerate(kinds):
             c_j = cache[f"k{j}"] if cache is not None else None
             x, nc, a, bs = _apply_block(
                 cfg, kind, p[f"k{j}"], x, positions,
-                backend=backend, cache=c_j, cache_pos=cache_pos,
-                kv_view=kv_view, chunk=rc.attn_chunk, want_state=want_state,
+                backend=backend, cache=c_j, layer=layer, cache_pos=cache_pos,
+                kv_view=view, chunk=rc.attn_chunk, want_state=want_state,
             )
             if nc is not None:
                 ncache[f"k{j}"] = nc
@@ -400,48 +410,35 @@ def forward(
 
     for gi, g in enumerate(groups):
         gp = params["groups"][gi]
-        gc = caches[gi] if caches is not None else None
 
-        def one_layer(x, p_slice, c_slice, _kinds=g.kinds):
-            fn = lambda x_, p_, c_: superblock(_kinds, x_, p_, c_)
+        def step(carry, xs, _kinds=g.kinds):
+            # the group's stacked caches ride in the carry and every layer
+            # updates them in place at its index; as scan xs/ys each layer
+            # would slice its cache out and write a fresh stack back
+            x, aux, c = carry
+            p_slice, layer = xs
+            fn = lambda x_, p_, c_, l_: superblock(_kinds, x_, p_, c_, l_)
             if rc.remat in ("block", "full"):
                 fn = jax.checkpoint(
                     fn,
                     policy=None if rc.remat == "full" else jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
                 )
-            return fn(x, p_slice, c_slice)
+            x, c, a, st = fn(x, p_slice, c, layer)
+            return (x, aux + a, c), st
 
+        carry = (x, aux_total, caches[gi] if caches is not None else None)
         if rc.scan_layers and g.repeats > 1:
-            def step(carry, xs, _g=g):
-                x, aux = carry
-                if gc is not None:
-                    p_slice, c_slice = xs
-                else:
-                    p_slice, c_slice = xs, None
-                x, nc, a, st = one_layer(x, p_slice, c_slice)
-                return (x, aux + a), (nc, st)
-
-            xs = (gp, gc) if gc is not None else gp
-            (x, aux_total), (nc, st) = jax.lax.scan(step, (x, aux_total), xs)
-            new_caches.append(nc)
-            stats_groups.append(st)
+            layers = jnp.arange(g.repeats, dtype=jnp.int32)
+            carry, st = jax.lax.scan(step, carry, (gp, layers))
         else:
-            ncs, sts = [], []
+            sts = []
             for i in range(g.repeats):
-                p_slice = jax.tree.map(lambda a, i=i: a[i], gp)
-                c_slice = jax.tree.map(lambda a, i=i: a[i], gc) if gc is not None else None
-                x, nc, a, st = one_layer(x, p_slice, c_slice)
-                aux_total = aux_total + a
-                ncs.append(nc)
-                sts.append(st)
-            if ncs and ncs[0] is not None:
-                new_caches.append(jax.tree.map(lambda *xs: jnp.stack(xs), *ncs))
-            else:
-                new_caches.append(None)
-            if sts and sts[0] is not None:
-                stats_groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *sts))
-            else:
-                stats_groups.append(None)
+                carry, st_i = step(carry, (jax.tree.map(lambda a, i=i: a[i], gp), i))
+                sts.append(st_i)
+            st = jax.tree.map(lambda *xs: jnp.stack(xs), *sts) if sts[0] is not None else None
+        x, aux_total, gc = carry
+        new_caches.append(gc)
+        stats_groups.append(st)
 
     x = rms_norm(params["final_norm"], x, cfg.rms_eps)
     x = constrain(x, "batch", "seq", "act_embed")

@@ -236,27 +236,28 @@ def param_pspecs(spec: MeshSpec, params):
     )
 
 
-def _cache_pspec(spec: MeshSpec, rc: RunConfig, leaf) -> P:
+def _cache_pspec(spec: MeshSpec, rc: RunConfig, name: str, leaf) -> P:
     """KV cache partition: paged pools replicate over dp (pages are shared
-    by all rows) and shard the head axis over tp when present (GQA k/v:
-    (L, P+1, bs, kv, hd)); MLA latents and the per-token scale planes have
-    no head axis and replicate. Dense layouts shard batch over dp (axis 1)
-    plus heads over tp."""
+    by all rows); dense layouts shard batch over dp (axis 1). GQA ``k``/``v``
+    leaves shard their heads over tp on axis 3: the head-major kv*hd feature
+    axis of a paged pool (L, P+1, bs, kv*hd), whose tp blocks are whole
+    heads since tp divides kv (``validate``), or the kv axis of a dense
+    (L, B, cap, kv, hd) buffer. MLA latents (``ckv``, ``kr``), SSM state and
+    the per-token scale planes have no head axis and replicate over tp."""
     shape = getattr(leaf, "shape", ())
     assign: dict = {}
-    if rc.kv_layout == "paged":
-        if len(shape) == 5 and spec.tp > 1 and shape[3] % spec.tp == 0:
-            assign[3] = spec.tp_axis
-    else:
-        if len(shape) >= 2 and spec.dp > 1 and shape[1] % spec.dp == 0:
-            assign[1] = spec.dp_axis
-        if len(shape) == 5 and spec.tp > 1 and shape[3] % spec.tp == 0:
-            assign[3] = spec.tp_axis
+    if rc.kv_layout != "paged" and len(shape) >= 2 and spec.dp > 1 \
+            and shape[1] % spec.dp == 0:
+        assign[1] = spec.dp_axis
+    if name in ("k", "v") and spec.tp > 1 and shape[3] % spec.tp == 0:
+        assign[3] = spec.tp_axis
     return _axis_spec(len(shape), assign) if assign else P()
 
 
 def cache_pspecs(spec: MeshSpec, rc: RunConfig, caches):
-    return jax.tree.map(lambda leaf: _cache_pspec(spec, rc, leaf), caches)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: _cache_pspec(spec, rc, _path_keys(path)[-1], leaf), caches
+    )
 
 
 def _place(mesh: Mesh, tree, pspecs):

@@ -104,12 +104,13 @@ def abstract_caches(
     )
 
 
-# paged layout: one KV leaf is a page pool (layers, pages+1, block, ...) —
+# paged layout: one KV leaf is a page pool (layers, pages+1, block, F) —
 # pages replicate (any slot's block table must reach any page from its data
-# shard) and the pool shards on heads, the vLLM-style TP cache split
+# shard) and a GQA pool shards its head-major kv*hd features on heads, the
+# vLLM-style TP cache split
 _PAGED_CACHE_AXES = {
-    "k": ("layers", None, None, "cache_heads", None),
-    "v": ("layers", None, None, "cache_heads", None),
+    "k": ("layers", None, None, "cache_heads"),
+    "v": ("layers", None, None, "cache_heads"),
     "k_scale": ("layers", None, None),
     "v_scale": ("layers", None, None),
     "ckv": ("layers", None, None, None),

@@ -7,6 +7,11 @@ serves: GQA and MLA pools, float and int8 KV, decode (Sq=1) and mixed
 prefill+decode widths, sliding windows, and every block-table edge case
 (partial last page, single-page rows, empty/idle rows, stale trash pages).
 
+The kernel reads the layer-stacked pool by layer index; every case runs on
+a 3-layer stack whose layers hold different contents, and the twin reads
+the one layer sliced out on its own, so a kernel that read another layer
+would not match.
+
 Outputs agree to float-accumulation order (online softmax reassociates the
 sum); the serving-level acceptance is exact: the scheduler's greedy token
 stream through the Pallas path is bit-identical to the twin's, and the
@@ -37,19 +42,30 @@ RC = RunConfig(
 )
 
 TOL = 2e-5  # float-accumulation-order headroom; values are O(1)
+LAYERS = 3  # every pool is a layer stack of this depth, layers all distinct
 
 
 def _pool(P, bs, feat, int8, seed):
-    """One paged cache buffer (pages+1 rows; last row is the trash page)."""
+    """One layer-stacked paged cache buffer, as the cache stores it:
+    (LAYERS, pages+1, bs, F) with the last page the trash page, F the
+    head-major feature axis, int8 token scales (LAYERS, pages+1, bs)."""
     r = np.random.default_rng(seed)
-    data = jnp.asarray(r.standard_normal((P + 1, bs) + feat).astype(np.float32))
+    F = int(np.prod(feat))
+    data = r.standard_normal((LAYERS * (P + 1), bs, F)).astype(np.float32)
     if not int8:
-        return {"k": data}
-    q, s = _quantize_kv(data)
-    return {"k": q, "k_scale": s}
+        return {"k": jnp.asarray(data.reshape(LAYERS, P + 1, bs, F))}
+    q, s = _quantize_kv(jnp.asarray(data))
+    return {"k": q.reshape(LAYERS, P + 1, bs, F),
+            "k_scale": s.reshape(LAYERS, P + 1, bs)}
 
 
-def _view(rows, bs, MB, P, seed=0):
+def _one_layer(cache: dict, view: KVView):
+    """The view's layer sliced out as a stack of one, and a view of it."""
+    return ({n: b[view.layer][None] for n, b in cache.items()},
+            dataclasses.replace(view, layer=0))
+
+
+def _view(rows, bs, MB, P, seed=0, layer=1):
     """KVView for per-row (pos, lens) specs; pages assigned disjointly,
     unused table entries left on the trash page (id P) like BlockManager."""
     r = np.random.default_rng(seed)
@@ -65,42 +81,41 @@ def _view(rows, bs, MB, P, seed=0):
             tables[b, m] = ids[nxt]
             nxt += 1
     return KVView(jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(tables),
-                  block_size=bs, layout="paged")
+                  block_size=bs, layout="paged", layer=jnp.int32(layer))
 
 
 def _gqa_case(rows, *, kv=2, group=3, hd=8, sq=1, bs=4, MB=3, int8=True,
-              window=None, seed=0):
+              window=None, seed=0, layer=1):
     B = len(rows)
     P = B * MB
-    view = _view(rows, bs, MB, P, seed=seed)
-    kc = {k.replace("k", "k", 1): v for k, v in _pool(P, bs, (kv, hd), int8, seed + 1).items()}
+    view = _view(rows, bs, MB, P, seed=seed, layer=layer)
+    kc = _pool(P, bs, (kv, hd), int8, seed + 1)
     vc = {k.replace("k", "v", 1): v for k, v in _pool(P, bs, (kv, hd), int8, seed + 2).items()}
     cache = {**kc, **vc}
     q = jnp.asarray(np.random.default_rng(seed + 3)
                     .standard_normal((B, sq, kv * group, hd)).astype(np.float32))
 
     out = flash_paged_decode(
-        q,
-        (cache["k"].reshape(P + 1, bs, kv * hd),),
-        (cache.get("k_scale"),),
-        cache["v"].reshape(P + 1, bs, kv * hd),
-        cache.get("v_scale"),
-        view.tables, view.pos, view.kv_len,
+        q, (cache["k"],), (cache.get("k_scale"),), cache["v"], cache.get("v_scale"),
+        view.tables, view.pos, view.kv_len, view.layer,
         kv_heads=kv, causal=True, window=window, interpret=True,
     )
-    k_full = kv_cache_read(cache, "k", q.dtype, kv_len=view.kv_len, view=view)
-    v_full = kv_cache_read(cache, "v", q.dtype, kv_len=view.kv_len, view=view)
+    one, view1 = _one_layer(cache, view)
+    k_full, v_full = (
+        kv_cache_read(one, n, q.dtype, kv_len=view.kv_len, view=view1)
+        .reshape(B, -1, kv, hd) for n in ("k", "v"))
     ref = blockwise_attention(q, k_full, v_full, q_offset=view.pos,
                               kv_len=view.kv_len, causal=True, window=window)
     return np.asarray(out), np.asarray(ref)
 
 
 # --------------------------------------------------------------- GQA anchors
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("sq", [1, 3])
-def test_gqa_kernel_matches_twin(int8, sq):
+def test_gqa_kernel_matches_twin(int8, sq, layer):
     rows = [(5, 1), (0, sq), (0, 0), (10, 1)]  # partial page / fresh / idle / near-full
-    out, ref = _gqa_case(rows, sq=sq, int8=int8)
+    out, ref = _gqa_case(rows, sq=sq, int8=int8, layer=layer)
     np.testing.assert_allclose(out, ref, atol=TOL, rtol=0)
 
 
@@ -125,16 +140,17 @@ def test_idle_rows_emit_zeros():
 
 
 # --------------------------------------------------------------- MLA anchors
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("sq", [1, 3])
-def test_mla_kernel_matches_twin(int8, sq):
+def test_mla_kernel_matches_twin(int8, sq, layer):
     """Two K parts concatenated per page in-register ([ckv ; kr], single
     latent head), V = the ckv pool — the absorbed-decode MLA layout."""
     lora, rope_d, h = 32, 16, 4
     rows = [(5, sq), (0, 0), (11 - sq, sq)]
     B, bs, MB = len(rows), 4, 4
     P = B * MB
-    view = _view(rows, bs, MB, P, seed=7)
+    view = _view(rows, bs, MB, P, seed=7, layer=layer)
     ckv = _pool(P, bs, (lora,), int8, 8)
     kr = {k.replace("k", "kr", 1): v for k, v in _pool(P, bs, (rope_d,), int8, 9).items()}
     cache = {"ckv": ckv["k"], "kr": kr["kr"]}
@@ -147,11 +163,12 @@ def test_mla_kernel_matches_twin(int8, sq):
         q, (cache["ckv"], cache["kr"]),
         (cache.get("ckv_scale"), cache.get("kr_scale")),
         cache["ckv"], cache.get("ckv_scale"),
-        view.tables, view.pos, view.kv_len,
+        view.tables, view.pos, view.kv_len, view.layer,
         kv_heads=1, causal=True, interpret=True,
     )
-    ckv_full = kv_cache_read(cache, "ckv", q.dtype, kv_len=view.kv_len, view=view)
-    kr_full = kv_cache_read(cache, "kr", q.dtype, kv_len=view.kv_len, view=view)
+    one, view1 = _one_layer(cache, view)
+    ckv_full = kv_cache_read(one, "ckv", q.dtype, kv_len=view.kv_len, view=view1)
+    kr_full = kv_cache_read(one, "kr", q.dtype, kv_len=view.kv_len, view=view1)
     k_eff = jnp.concatenate([ckv_full, kr_full], axis=-1)[:, :, None, :]
     ref = blockwise_attention(q, k_eff, ckv_full[:, :, None, :],
                               q_offset=view.pos, kv_len=view.kv_len, causal=True)
@@ -283,17 +300,21 @@ def test_scheduler_greedy_tokens_identical_pallas_vs_xla(arch, policy):
 
 
 # --------------------------------------------------- decode-step HLO gather
-_GATHER = re.compile(r"=\s*[a-z0-9]+\[([0-9,]*)\][^=]*?\bgather\(")
+_INSTR = re.compile(r"%([\w.\-]+) = [a-z0-9]+\[([0-9,]*)\]")
+_GATHER_OPERAND = re.compile(r"\bgather\(%([\w.\-]+)")
 
 
-def _wide_gathers(hlo: str) -> list[str]:
-    """Gather instructions whose result rank >= 4 — the materialized
-    ``pool[tables]`` reads ((B, MB, bs, ...) are 4-5D; embedding lookups and
-    table indexing are <= 3D)."""
+def _pool_gathers(hlo: str, pool_shapes: set) -> list[str]:
+    """Gather instructions whose operand is a paged pool — the whole layer
+    stack or one layer of it, by shape: the materialized ``pool[tables]``
+    read. Other gathers (the step's ``take_along_axis`` of each row's last
+    hidden column, embedding lookups, table indexing) read other shapes."""
+    shapes = {m.group(1): tuple(int(d) for d in m.group(2).split(",") if d)
+              for m in _INSTR.finditer(hlo)}
     hits = []
     for ln in hlo.splitlines():
-        m = _GATHER.search(ln)
-        if m and m.group(1) and m.group(1).count(",") >= 3:
+        m = _GATHER_OPERAND.search(ln)
+        if m and shapes.get(m.group(1)) in pool_shapes:
             hits.append(ln.strip()[:120])
     return hits
 
@@ -309,6 +330,8 @@ def test_decode_step_hlo_has_no_pool_gather():
     params = init(cfg, rc, jax.random.PRNGKey(2))
     B, cap = 2, 16
     caches = init_caches(cfg, rc, B, cap)
+    pool_shapes = {s for leaf in jax.tree.leaves(caches)
+                   for s in (leaf.shape, leaf.shape[1:])}
     tokens = jnp.ones((B, 5), jnp.int32)
     pos = jnp.asarray([3, 0], jnp.int32)
     lens = jnp.asarray([1, 0], jnp.int32)
@@ -321,10 +344,32 @@ def test_decode_step_hlo_has_no_pool_gather():
 
     try:
         set_paged_impl("xla")
-        wide_twin = _wide_gathers(lower())
+        twin = _pool_gathers(lower(), pool_shapes)
         set_paged_impl("pallas_interpret")
-        wide_kernel = _wide_gathers(lower())
+        kernel = _pool_gathers(lower(), pool_shapes)
     finally:
         set_paged_impl(None)
-    assert wide_twin, "detector sanity: twin path should materialize pool gathers"
-    assert not wide_kernel, f"pool gather survived on the Pallas path:\n" + "\n".join(wide_kernel)
+    assert twin, "detector sanity: twin path should materialize pool gathers"
+    assert not kernel, f"pool gather survived on the Pallas path:\n" + "\n".join(kernel)
+
+
+def test_paged_step_reads_the_pool_in_place():
+    """A paged step on the kernel path records ``kv.pool: in_place`` (the
+    kernel read the layer-stacked pool by layer index) beside
+    ``attn.paged: pallas``, with no fallback, in health()["kernels"]."""
+    from repro.serve import Request, Scheduler
+
+    cfg = get_config("qwen3-0.6b_smoke")
+    rc = dataclasses.replace(RC, kv_layout="paged", block_size=4)
+    params = init(cfg, rc, jax.random.PRNGKey(3))
+    try:
+        set_paged_impl("pallas_interpret")
+        s = Scheduler(cfg, rc, params, capacity=16, max_batch=2)
+        s.submit(Request(rid=0, prompt=[1, 2, 3, 4, 5, 6], max_new=2))
+        s.run()
+        kernels = s.health()["kernels"]
+    finally:
+        set_paged_impl(None)
+    assert set(kernels["paths"]["kv.pool"]) == {"in_place"}, kernels
+    assert set(kernels["paths"]["attn.paged"]) == {"pallas"}, kernels
+    assert not kernels["fallbacks"], kernels

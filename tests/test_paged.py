@@ -129,17 +129,22 @@ def test_scheduler_matches_legacy_engine_greedy():
 
 
 # --------------------------------------------------------- length-masked read
+def _stacked(cache: dict, layers: int) -> dict:
+    """A layer group's cache: each buffer stacked along a leading layers axis."""
+    return {n: jnp.stack([b] * layers) for n, b in cache.items()}
+
+
 def test_dense_int8_read_masks_stale_tail():
     """Slot reuse: positions at/beyond kv_len dequantize to exact zeros even
     when the buffer still holds a previous occupant's quantized tokens."""
     cfg = get_config("qwen3-0.6b_smoke")
-    cache = init_kv_cache(cfg, 2, 8, jnp.int8)
+    cache = _stacked(init_kv_cache(cfg, 2, 8, jnp.int8), 2)
     rng = np.random.default_rng(0)
     full = jnp.asarray(rng.normal(size=(2, 8, cfg.num_kv_heads, cfg.resolved_head_dim)),
                        jnp.float32)
-    cache = kv_cache_write(cache, ("k",), (full,), 0)     # old occupant: 8 tokens
+    cache = kv_cache_write(cache, ("k",), (full,), 0, layer=1)  # old occupant: 8 tokens
     kv_len = jnp.asarray([3, 5], jnp.int32)               # new occupants shorter
-    out = kv_cache_read(cache, "k", jnp.float32, kv_len=kv_len)
+    out = kv_cache_read(cache, "k", jnp.float32, kv_len=kv_len, layer=1)
     assert np.abs(np.asarray(out[0, :3])).sum() > 0
     assert np.asarray(out[0, 3:]).sum() == 0.0
     assert np.asarray(out[1, 5:]).sum() == 0.0
@@ -156,20 +161,22 @@ def test_paged_write_read_matches_dense():
     pos = jnp.asarray([0, 2], jnp.int32)
     lens = jnp.asarray([6, 3], jnp.int32)
 
-    dense = init_kv_cache(cfg, B, capacity, jnp.int8)
-    view_d = KVView(pos=pos, lens=lens, tables=None, block_size=bs, layout="dense")
+    dense = _stacked(init_kv_cache(cfg, B, capacity, jnp.int8), 2)
+    view_d = KVView(pos=pos, lens=lens, tables=None, block_size=bs, layout="dense",
+                    layer=1)
     dense = kv_cache_write(dense, ("k",), (kv,), None, view=view_d)
-    out_d = kv_cache_read(dense, "k", jnp.float32, kv_len=pos + lens)
+    out_d = kv_cache_read(dense, "k", jnp.float32, kv_len=pos + lens, layer=1)
 
     mgr = BlockManager(B * capacity // bs, bs, B, capacity)
     assert mgr.extend(0, 6) and mgr.extend(1, 5)
-    pool = init_kv_cache(cfg, mgr.num_pages + 1, bs, jnp.int8)
+    pool = _stacked(init_kv_cache(cfg, mgr.num_pages + 1, bs, jnp.int8, paged=True), 2)
     view_p = KVView(pos=pos, lens=lens, tables=jnp.asarray(mgr.tables),
-                    block_size=bs, layout="paged")
+                    block_size=bs, layout="paged", layer=1)
     pool = kv_cache_write(pool, ("k",), (kv,), None, view=view_p)
     out_p = kv_cache_read(pool, "k", jnp.float32, kv_len=pos + lens, view=view_p)
 
-    assert np.array_equal(np.asarray(out_d), np.asarray(out_p))
+    # the paged pool stores heads head-major on one feature axis
+    assert np.array_equal(np.asarray(out_d).reshape(out_p.shape), np.asarray(out_p))
 
 
 # ----------------------------------------------------------------- allocator
