@@ -1,9 +1,21 @@
-"""Plain float32 Qwen3 forward: the reference that decides ``correct``.
+"""The Qwen3 family: what the benchmark needs to know of a Qwen3 block.
 
-Straight ``jax.numpy``, every matrix product at ``Precision.HIGHEST``, no
-kernels, no cache, no batching: one sequence at a time through the whole
-stack, causal attention over the whole sequence, computed layer by layer and
-in blocks of queries so that it fits beside nothing else on the chip.
+A configuration file names its family with ``"arch"``; the harness loads
+``bench/arch/<arch>.py`` by path and reads from it:
+
+- ``KEYS``: configuration-file key -> registry ``ModelConfig`` field, checked
+  against the registry at every run (a ``reduced`` key is applied through it);
+- ``GEMMS`` and ``ATTENTION``: the quantized GEMMs and the paged attention
+  that every run must have traced to the kernel path;
+- ``logit_gaps``: the plain float32 reference that decides ``correct``;
+- ``layer_gemms``, ``attn_cost`` and ``step_useful_time``: the block's
+  operations and bytes, for the per-layer metrics (bench/costs.py).
+
+The reference is straight ``jax.numpy``, every matrix product at
+``Precision.HIGHEST``, no kernels, no cache, no batching: one sequence at a
+time through the whole stack, causal attention over the whole sequence,
+computed layer by layer and in blocks of queries so that it fits beside
+nothing else on the chip.
 
 The block follows the published Qwen3 description (hf:Qwen/Qwen3-0.6B,
 hf:Qwen/Qwen3-8B): pre-norm RMSNorm (eps from the configuration), q/k/v
@@ -25,6 +37,19 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.costs import BF16, Gemm, bits_for
+
+KEYS = {
+    "hidden_size": "d_model", "intermediate_size": "d_ff",
+    "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+GEMMS = ("attn.q", "attn.k", "attn.v", "attn.o", "mlp.gate", "mlp.up", "mlp.down")
+ATTENTION = "attn.paged"
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512      # queries per attention block
@@ -144,10 +169,11 @@ def hidden(params, dims: Dims, seq: list[int], capacity: int):
     return x
 
 
-def logit_gaps(params, dims: Dims, prompt: list[int], served: list[int],
+def logit_gaps(params, config: dict, prompt: list[int], served: list[int],
                capacity: int) -> np.ndarray:
     """Reference gap of each served token: the reference's best logit at that
     position minus its logit for the token the program served."""
+    dims = Dims.of(config)
     x = hidden(params, dims, list(prompt) + list(served[:-1]), capacity)
     first = len(prompt) - 1
     h = x[first: first + len(served)]
@@ -163,3 +189,49 @@ def logit_gaps(params, dims: Dims, prompt: list[int], served: list[int],
         g = _gaps(hb, params["final_norm"]["scale"], unembed, sv, dims)
         out.append(np.asarray(g)[:n])
     return np.concatenate(out)
+
+
+# ------------------------------------------------------------------- costs
+def layer_gemms(config: dict, policy: str) -> list[Gemm]:
+    """The quantizable GEMMs of one Qwen3 block, (K, N) as the program holds
+    them."""
+    d, ff = config["hidden_size"], config["intermediate_size"]
+    h, kv, hd = (config["num_attention_heads"], config["num_key_value_heads"],
+                 config["head_dim"])
+    shapes = [("attn.q", d, h * hd), ("attn.k", d, kv * hd), ("attn.v", d, kv * hd),
+              ("attn.o", h * hd, d), ("mlp.gate", d, ff), ("mlp.up", d, ff),
+              ("mlp.down", ff, d)]
+    return [Gemm(n, k, m, bits_for(n, policy)) for n, k, m in shapes]
+
+
+def attn_cost(config: dict, rows, block_size: int, kv_bytes: int = BF16) -> tuple[float, float]:
+    """(operations, bytes) of one layer's paged attention over ``rows``, each
+    (pos, n): n queries at positions pos..pos+n-1 against the causal context.
+    Bytes are the KV pages the rows' live lengths span, read once, plus the
+    queries and outputs."""
+    h, kv, hd = (config["num_attention_heads"], config["num_key_value_heads"],
+                 config["head_dim"])
+    ops = byts = 0.0
+    for pos, n in rows:
+        visible = n * pos + n * (n + 1) / 2        # sum of causal context lengths
+        ops += 4.0 * h * hd * visible              # QK^T and PV
+        pages = -(-(pos + n) // block_size)
+        byts += pages * block_size * 2 * kv * hd * kv_bytes
+        byts += 2 * n * h * hd * BF16
+    return ops, byts
+
+
+def step_useful_time(config: dict, policy: str, rows, pk: dict) -> float:
+    """Seconds one step's useful work takes at the device's peaks: each real
+    (unpadded) token through every GEMM at that GEMM's precision, the
+    vocabulary projection of each scheduled row in bf16, and attention over
+    the real causal context in bf16."""
+    tokens = sum(n for _, n in rows)
+    t = 0.0
+    for g in layer_gemms(config, policy):
+        peak = pk["bf16_flops"] if g.bits == 16 else pk["int8_ops"]
+        t += config["num_hidden_layers"] * 2.0 * tokens * g.k * g.n / peak
+    t += 2.0 * len(rows) * config["hidden_size"] * config["vocab_size"] / pk["bf16_flops"]
+    a_ops, _ = attn_cost(config, rows, 1)
+    t += config["num_hidden_layers"] * a_ops / pk["bf16_flops"]
+    return t

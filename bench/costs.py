@@ -1,12 +1,14 @@
 """Operations and bytes of the serving step's kernels, from shapes alone.
 
 A fused GEMM call is priced from the shapes it ran with, as the device trace
-gives them; attention and a step's useful work from the widths of a
-configuration file (bench/configs/*.json), its policy giving the precision of
-each GEMM. These functions count the multiply-adds of the algorithm (2
-operations each) and the bytes a call must move at the least (inputs read
-once, outputs written once, weights at their stored width). The least time a call can take on a device is the larger of its
-operations over the peak for its precision and its bytes over the memory
+gives them. Attention and a step's useful work depend on the block, so they
+come from the configuration's architecture family (bench/arch/<arch>.py),
+priced from the widths of the configuration file (bench/configs/*.json), its
+policy giving the precision of each GEMM. These functions count the
+multiply-adds of the algorithm (2 operations each) and the bytes a call must
+move at the least (inputs read once, outputs written once, weights at their
+stored width). The least time a call can take on a device is the larger of
+its operations over the peak for its precision and its bytes over the memory
 bandwidth (bench/peaks.json).
 """
 
@@ -31,7 +33,7 @@ def peaks(device_kind: str) -> dict:
 
 def gemm_bits(policy: str) -> dict:
     """GEMM-name pattern -> bits, from a QuantPolicy string such as
-    ``attn.*=int8:prequant:per_token,mlp.*=int8:...,*=bf16``."""
+    ``*=int8:prequant:per_token,lm_head=bf16``."""
     out = {}
     for rule in policy.split(","):
         pat, spec = rule.split("=", 1)
@@ -57,18 +59,6 @@ class Gemm:
     bits: int
 
 
-def layer_gemms(config: dict, policy: str) -> list[Gemm]:
-    """The quantizable GEMMs of one Qwen3 block, (K, N) as the program holds
-    them."""
-    d, ff = config["hidden_size"], config["intermediate_size"]
-    h, kv, hd = (config["num_attention_heads"], config["num_key_value_heads"],
-                 config["head_dim"])
-    shapes = [("attn.q", d, h * hd), ("attn.k", d, kv * hd), ("attn.v", d, kv * hd),
-              ("attn.o", h * hd, d), ("mlp.gate", d, ff), ("mlp.up", d, ff),
-              ("mlp.down", ff, d)]
-    return [Gemm(n, k, m, bits_for(n, policy)) for n, k, m in shapes]
-
-
 DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "u8": 1,
                "s4": 0.5, "u4": 0.5}
 
@@ -89,34 +79,20 @@ def least_time(ops: float, byts: float, bits: int, pk: dict) -> float:
     return max(ops / peak, byts / pk["hbm_bytes_per_s"])
 
 
+def _family(config: dict):
+    from bench import run
+
+    return run.family(config)
+
+
 def attn_cost(config: dict, rows, block_size: int, kv_bytes: int = BF16) -> tuple[float, float]:
     """(operations, bytes) of one layer's paged attention over ``rows``, each
-    (pos, n): n queries at positions pos..pos+n-1 against the causal context.
-    Bytes are the KV pages the rows' live lengths span, read once, plus the
-    queries and outputs."""
-    h, kv, hd = (config["num_attention_heads"], config["num_key_value_heads"],
-                 config["head_dim"])
-    ops = byts = 0.0
-    for pos, n in rows:
-        visible = n * pos + n * (n + 1) / 2        # sum of causal context lengths
-        ops += 4.0 * h * hd * visible              # QK^T and PV
-        pages = -(-(pos + n) // block_size)
-        byts += pages * block_size * 2 * kv * hd * kv_bytes
-        byts += 2 * n * h * hd * BF16
-    return ops, byts
+    (pos, n): n queries at positions pos..pos+n-1 against the causal context,
+    as the configuration's family prices them."""
+    return _family(config).attn_cost(config, rows, block_size, kv_bytes)
 
 
 def step_useful_time(config: dict, policy: str, rows, pk: dict) -> float:
-    """Seconds one step's useful work takes at the device's peaks: each real
-    (unpadded) token through every GEMM at that GEMM's precision, the
-    vocabulary projection of each scheduled row in bf16, and attention over
-    the real causal context in bf16."""
-    tokens = sum(n for _, n in rows)
-    t = 0.0
-    for g in layer_gemms(config, policy):
-        peak = pk["bf16_flops"] if g.bits == 16 else pk["int8_ops"]
-        t += config["num_hidden_layers"] * 2.0 * tokens * g.k * g.n / peak
-    t += 2.0 * len(rows) * config["hidden_size"] * config["vocab_size"] / pk["bf16_flops"]
-    a_ops, _ = attn_cost(config, rows, 1)
-    t += config["num_hidden_layers"] * a_ops / pk["bf16_flops"]
-    return t
+    """Seconds one step's useful work over ``rows`` takes at the device's
+    peaks, as the configuration's family prices it."""
+    return _family(config).step_useful_time(config, policy, rows, pk)

@@ -4,6 +4,11 @@
 
 The cell is looked up in ``BENCHMARK.json``; its configuration is
 ``bench/configs/<config>.json`` and its traffic ``bench/traffic/<traffic>.json``.
+The configuration names its architecture family, ``bench/arch/<arch>.py``,
+which holds what is particular to the block: the sizes checked against the
+program's registry, the kernel names every run must trace, the reference and
+the cost model.
+
 The run builds the serving engine as the program's serve launcher does
 (weights from ``--seed``, made on the device; quantization surgery; the
 continuous-batching ``Scheduler``), warms both step widths, starts the
@@ -12,7 +17,7 @@ open-loop load, and after the warm-up measures ``--seconds`` seconds, driving
 when it is due, and every token is stamped after the tick that emitted it.
 
 After the window, a sample of the finished requests is checked against the
-plain float32 reference (bench/reference.py) and the result is printed as one
+plain float32 reference of the family and the result is printed as one
 JSON line, last on standard output. ``--trace 1`` runs the program's tracer
 and the device profiler through the window and reports the per-layer metrics
 of ``BENCHMARK.json`` (bench/metrics/<name>.py) instead of the end-to-end ones.
@@ -48,9 +53,6 @@ import numpy as np  # noqa: E402
 
 from bench import stats, traffic as traffic_mod  # noqa: E402
 
-QUANT_GEMMS = ("attn.q", "attn.k", "attn.v", "attn.o", "mlp.gate", "mlp.up", "mlp.down")
-PAGED = "attn.paged"
-
 
 # ------------------------------------------------------------------ lookups
 def load_json(path: Path) -> dict:
@@ -78,19 +80,39 @@ def traffic_file(name: str) -> dict:
 
 
 def available(kind: str) -> list[str]:
-    """Names of the configurations, traffic mixes or metric readers on disk."""
-    ext = ".py" if kind == "metrics" else ".json"
+    """Names of the configurations, traffic mixes, metric readers or
+    architecture families on disk."""
+    ext = ".py" if kind in ("metrics", "arch") else ".json"
     return sorted(p.name[: -len(ext)] for p in (BENCH / kind).glob(f"*{ext}")
                   if not p.name.startswith("_"))
 
 
-def metric_reader(name: str):
-    """The module ``bench/metrics/<name>.py`` (names may hold dots)."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def load_module(path: Path, name: str):
+    """The module at ``path``, loaded once under ``name`` (in ``sys.modules``,
+    so that its dataclasses resolve; loaded again where ``path`` differs)."""
+    mod = sys.modules.get(name)
+    if mod is not None and mod.__file__ == str(path):
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str):
+    """The module ``bench/metrics/<name>.py`` (names may hold dots)."""
+    return load_module(BENCH / "metrics" / f"{name}.py", f"bench_metric_{name}")
+
+
+def family(config: dict):
+    """The architecture family ``bench/arch/<arch>.py`` that the configuration
+    file names under ``arch`` (there is no default)."""
+    if "arch" not in config:
+        raise ValueError(f"{config['name']}: the configuration names no architecture "
+                         f"family (\"arch\")")
+    name = config["arch"]
+    return load_module(BENCH / "arch" / f"{name}.py", f"bench_arch_{name}")
 
 
 def per_layer_for(cell: str) -> list[dict]:
@@ -104,22 +126,24 @@ def end_to_end_for(cell: str) -> list[dict]:
 
 
 # ------------------------------------------------------------ model set-up
-MODEL_KEYS = {  # configuration file key -> registry ModelConfig field
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "rms_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
-
 def model_config(config: dict):
-    """The registry ModelConfig cut to the file's depth; every other size in
-    the file has to match the registry's."""
+    """The registry ModelConfig, cut as the file's ``reduced`` says (each key
+    through the family's ``KEYS``, to the value the file holds); after the
+    cut every size in ``KEYS`` has to match the registry's."""
     from repro.configs.base import get_config
 
-    cfg = get_config(config["registry"]).replace(num_layers=config["num_hidden_layers"])
-    for key, attr in MODEL_KEYS.items():
+    keys = family(config).KEYS
+    cut = {}
+    for key, r in config["reduced"].items():
+        if key not in keys:
+            raise ValueError(f"{config['name']}: reduced key {key!r} is not in the "
+                             f"{config['arch']} family's KEYS")
+        if r["here"] != config[key]:
+            raise ValueError(f"{config['name']}: reduced {key} is {r['here']} here "
+                             f"but the file holds {config[key]}")
+        cut[keys[key]] = r["here"]
+    cfg = get_config(config["registry"]).replace(**cut)
+    for key, attr in keys.items():
         if getattr(cfg, attr) != config[key]:
             raise ValueError(f"{config['name']}: {key}={config[key]} but the "
                              f"registry's {attr} is {getattr(cfg, attr)}")
@@ -167,12 +191,12 @@ def build(config: dict, policy: str, seed: int, tracer=None):
     return cfg, rc, scheduler(cfg, rc, params, config, seed, tracer)
 
 
-def kernel_faults(health: dict, path: str) -> list[str]:
-    """Every quantized GEMM and paged attention must have been traced to
-    ``path`` only, with no fallback."""
+def kernel_faults(health: dict, path: str, family) -> list[str]:
+    """Every quantized GEMM and the paged attention that ``family`` names
+    must have been traced to ``path`` only, with no fallback."""
     k = health["kernels"]
     errs = [f"fallback {n}: {r}" for n, r in k["fallbacks"].items()]
-    for name in (*QUANT_GEMMS, PAGED):
+    for name in (*family.GEMMS, family.ATTENTION):
         got = k["paths"].get(name)
         if not got:
             errs.append(f"{name} never traced")
@@ -345,16 +369,15 @@ def check(config: dict, seed: int, sample) -> dict:
 
     from repro.models import abstract_params
 
-    from bench import reference
     from bench.weights import make_weights
 
+    reference = family(config)
     cfg = model_config(config)
     rc = run_config(config, config["quant_policy"])
     params = make_weights(abstract_params(cfg, rc), seed)
-    dims = reference.Dims.of(config)
     worst, served, tokens_equal = 0.0, 0, 0
     for r in sample:
-        g = reference.logit_gaps(params, dims, r.req.prompt, r.req.out,
+        g = reference.logit_gaps(params, config, r.req.prompt, r.req.out,
                                  config["serving"]["capacity"])
         worst = max(worst, float(g.max()))
         served += len(g)
@@ -390,7 +413,8 @@ def run_cell(cell: str, config: dict, traffic: dict, *, seed: int, seconds: floa
     vocab = cfg.vocab_size
     warm_compile(sched, config, vocab)
     compiled = cached_programs(cache_dir) > cached
-    faults = kernel_faults(sched.health(), expect_path)
+    fam = family(config)
+    faults = kernel_faults(sched.health(), expect_path, fam)
     sv = config["serving"]
     planned = traffic_mod.plan(traffic, seed=seed, seconds=seconds,
                                max_batch=sv["max_batch"], vocab=vocab)
@@ -413,7 +437,8 @@ def run_cell(cell: str, config: dict, traffic: dict, *, seed: int, seconds: floa
         jax.profiler.stop_trace()
     t_close = time.perf_counter()
     mem = jax.devices()[0].memory_stats() or {}
-    faults += [f"after the window: {e}" for e in kernel_faults(sched.health(), expect_path)
+    faults += [f"after the window: {e}"
+               for e in kernel_faults(sched.health(), expect_path, fam)
                if "never traced" not in e]
     recs = drv.recs
     due_in = [r for r in recs if t_open <= r.due < t_end]

@@ -58,9 +58,10 @@ def test_step_that_ignores_the_q_norm_scale_fails(monkeypatch):
 def test_kernel_fallback_fails(monkeypatch):
     from bench import run
 
-    health = {"kernels": {"paths": {n: {"xla": 3} for n in (*run.QUANT_GEMMS, run.PAGED)},
+    qwen3 = run.family(config())
+    health = {"kernels": {"paths": {n: {"xla": 3} for n in (*qwen3.GEMMS, qwen3.ATTENTION)},
                           "fallbacks": {"attn.paged": {"mesh": 1}}}}
-    assert run.kernel_faults(health, "xla") == ["fallback attn.paged: {'mesh': 1}"]
-    assert any("want pallas" in e for e in run.kernel_faults(health, "pallas"))
+    assert run.kernel_faults(health, "xla", qwen3) == ["fallback attn.paged: {'mesh': 1}"]
+    assert any("want pallas" in e for e in run.kernel_faults(health, "pallas", qwen3))
     del health["kernels"]["paths"]["mlp.up"]
-    assert "mlp.up never traced" in run.kernel_faults(health, "xla")
+    assert "mlp.up never traced" in run.kernel_faults(health, "xla", qwen3)

@@ -1,4 +1,4 @@
-"""The plain float32 reference against the served path: chunked prefill
+"""The Qwen3 family's plain float32 reference against the served path: chunked prefill
 through mixed steps, then decode through the paged KV cache, with every GEMM
 unquantized and the whole program in float32, on the benchmark's seeded
 weights. The served greedy tokens must be the reference's best at every
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import bench.weights
-from bench import reference, run
+from bench import run
 from bench_smoke import SMOKE_STD, config
 
 
@@ -41,19 +41,21 @@ def _served(monkeypatch, seed: int):
 @pytest.mark.parametrize("seed", [3, 2**33 + 1])
 def test_served_tokens_are_the_references_best(monkeypatch, seed):
     cfgf, params, served = _served(monkeypatch, seed)
-    dims = reference.Dims.of(cfgf)
+    reference = run.family(cfgf)
     cap = cfgf["serving"]["capacity"]
     for prompt, out in served:
-        gaps = reference.logit_gaps(params, dims, prompt, out, cap)
+        gaps = reference.logit_gaps(params, cfgf, prompt, out, cap)
         assert len(gaps) == len(out)
         assert gaps.max() < 1e-4, gaps
 
 
 def test_a_changed_block_disagrees(monkeypatch):
     cfgf, params, served = _served(monkeypatch, 3)
+    reference = run.family(cfgf)
     wrong = dataclasses.replace(reference.Dims.of(cfgf), theta=1e4)   # RoPE base
+    monkeypatch.setattr(reference.Dims, "of", classmethod(lambda cls, config: wrong))
     cap = cfgf["serving"]["capacity"]
-    worst = max(reference.logit_gaps(params, wrong, p, o, cap).max() for p, o in served)
+    worst = max(reference.logit_gaps(params, cfgf, p, o, cap).max() for p, o in served)
     assert worst > 0.1
 
 

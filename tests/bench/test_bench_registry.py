@@ -1,5 +1,6 @@
-"""The harness finds configurations, traffic mixes and per-layer metrics by
-file name, and BENCHMARK.json names only what is on disk, consistently."""
+"""The harness finds configurations, traffic mixes, per-layer metrics and
+architecture families by file name, and BENCHMARK.json names only what is on
+disk, consistently."""
 
 import json
 
@@ -14,12 +15,13 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 def test_enumerates_by_file_name():
     assert {"qwen3-0.6b", "qwen3-8b-l12"} <= set(run.available("configs"))
     assert {"chat", "code", "reasoning"} <= set(run.available("traffic"))
+    assert "qwen3" in run.available("arch")
     assert {m["name"] for m in BENCH["per_layer"]} <= set(run.available("metrics"))
     assert not any(n.startswith("_") for n in run.available("metrics"))
 
 
 def test_a_new_file_is_found_without_an_edit(tmp_path, monkeypatch):
-    for kind in ("configs", "traffic", "metrics"):
+    for kind in ("configs", "traffic", "metrics", "arch"):
         (tmp_path / kind).mkdir()
     (tmp_path / "traffic" / "burst.json").write_text(json.dumps({"users": "x"}))
     (tmp_path / "metrics" / "new.metric.py").write_text("def read(ctx):\n    return 42.0\n")
@@ -27,6 +29,9 @@ def test_a_new_file_is_found_without_an_edit(tmp_path, monkeypatch):
     assert run.available("traffic") == ["burst"]
     assert run.traffic_file("burst") == {"users": "x"}
     assert run.metric_reader("new.metric").read(None) == 42.0
+    (tmp_path / "arch" / "fam.py").write_text("GEMMS = ('x.q',)\n")
+    assert run.available("arch") == ["fam"]
+    assert run.family({"name": "c", "arch": "fam"}).GEMMS == ("x.q",)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -60,3 +65,12 @@ def test_configs_reduced_keys_are_listed():
     for c in BENCH["configs"]:
         f = json.loads((run.ROOT / c["file"]).read_text())
         assert sorted(f["reduced"]) == sorted(c["reduced"]) and f["source"] == c["source"]
+
+
+@pytest.mark.parametrize("name", run.available("configs"))
+def test_each_config_names_a_family_on_disk(name):
+    config = run.config_file(name)
+    assert config["arch"] in run.available("arch")
+    fam = run.family(config)
+    assert set(config["reduced"]) <= set(fam.KEYS) and fam.GEMMS and fam.ATTENTION
+    assert run.model_config(config).num_layers == config["num_hidden_layers"]

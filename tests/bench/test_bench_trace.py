@@ -101,7 +101,7 @@ def test_readers_on_the_recorded_trace(trace):
     # roofline: least time from each call's shapes over the kernel's device
     # time; every call of this mixed step computes its whole 32 x 128 rows
     m, least = 32 * 128, 0.0
-    for g in costs.layer_gemms(config, config["quant_policy"]):
+    for g in run.family(config).layer_gemms(config, config["quant_policy"]):
         byts = m * g.k * 2 + g.k * g.n + m * g.n * 2 + (m + g.n) * 4
         least += 28 * costs.least_time(2.0 * m * g.k * g.n, byts, 8, ctx.peaks)
     step = next(m for m in trace.modules if m.dur == mixed)
