@@ -4,7 +4,8 @@ Puts the three records of a traced window on one clock: the harness's ticks
 and requests (host ``perf_counter``), the program's tracer spans (tick phases
 ``admit``/``plan``/``cow_drain``/``device_step``/``commit``, and a
 ``prefill``/``decode`` span for every scheduled row with its position and
-token count), and the device trace (bench/xtrace.py). Each per-layer metric
+token count), and the device trace (bench/xtrace.py) with the program's own
+``serve/*`` annotations (bench/phases.py reads those). Each per-layer metric
 of ``BENCHMARK.json`` is then read by its own module, bench/metrics/<name>.py,
 from a :class:`Ctx`; a reader that finds nothing to read returns None and the
 metric is left out of the line.
@@ -43,6 +44,9 @@ class Ctx:
     recs: list
     ticks: list                   # TickView of every tick that started in the window
     busy_s: float
+    serve: list = field(default_factory=list)    # serve/* annotations, profile clock
+    modules: list = field(default_factory=list)  # every xtrace.Module of the capture
+    record: dict = field(default_factory=dict)   # the tracer's spans, for bench/phases.py
 
     @property
     def chunk(self) -> int:
@@ -94,7 +98,9 @@ def build(res: dict, config: dict, device_kind: str,
                            for m in trace.modules if m.start + m.dur > lo and m.start < hi)
     ctx = Ctx(config=config, policy=res["policy"], peaks=costs.peaks(device_kind),
               t_open=t_open, t_end=t_end, window_s=t_end - t_open,
-              recs=res["recs"], ticks=ticks, busy_s=busy / 1e9)
+              recs=res["recs"], ticks=ticks, busy_s=busy / 1e9,
+              serve=trace.serve, modules=trace.modules,
+              record={k: res[k] for k in ("spans", "tracer_offset_s", "t_open", "t_end")})
     return ctx, offset, spans
 
 

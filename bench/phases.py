@@ -33,25 +33,11 @@ import sys
 
 from bench import layers, xtrace
 
-PREFIX = "serve/"
 # host work on the logits after the step: the copy to the host, the f32
 # widening, the finite scan and sampling
 LOGITS_HOST = ("logits_fetch", "logits_widen", "logits_check", "sample")
 OUTSIDE = "tick, outside its phases"
 BETWEEN = "between ticks"
-
-
-def annotations(pd) -> list[tuple[float, float, str]]:
-    """(start, end, name) of every ``serve/*`` annotation on the host planes
-    of a ``jax.profiler.ProfileData``, the prefix cut, outer spans first."""
-    out = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host"):
-            continue
-        for ln in plane.lines:
-            out += [(e.start_ns, e.start_ns + e.duration_ns, e.name[len(PREFIX):])
-                    for e in ln.events if e.name.startswith(PREFIX)]
-    return sorted(out, key=lambda a: (a[0], -a[1]))
 
 
 def labelled(anns) -> list[tuple[float, float, str]]:
@@ -167,7 +153,7 @@ def read(res: dict, config: dict, device_kind: str, pd) -> tuple:
     harness's breakdown) of one traced run."""
     trace = xtrace.from_profile(pd)
     ctx, offset, spans = layers.build(res, config, device_kind, trace)
-    anns = annotations(pd)
+    anns = trace.serve
     lo, hi = ctx.t_open * 1e9 + offset, ctx.t_end * 1e9 + offset
     compiles = [(s * 1e9 + offset, (s + d) * 1e9 + offset, "compile")
                 for n, pid, _, s, d, _ in spans if pid == layers.PID_SCHED and n == "compile"]

@@ -18,16 +18,20 @@ def percentile(values, q: float) -> float | None:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def ttft_samples(requests, t_open: float, t_end: float) -> list[float]:
-    """Due time to first token of every request due in [t_open, t_end); an
-    unanswered one counts its wait until t_end. ``requests`` have ``due``
-    (absolute seconds) and ``times`` (the emission time of each token)."""
+def ttft_samples(requests, t_open: float, t_end: float,
+                 t_stop: float | None = None) -> list[float]:
+    """Due time to first token of every request due in [t_open, t_end); one
+    unanswered at ``t_stop`` (by default t_end; later where the run followed
+    the window's requests on to their first tokens) counts its wait until
+    then. ``requests`` have ``due`` (absolute seconds) and ``times`` (the
+    emission time of each token)."""
+    stop = t_end if t_stop is None else t_stop
     out = []
     for r in requests:
         if not (t_open <= r.due < t_end):
             continue
         first = r.times[0] if r.times else None
-        out.append((first if first is not None and first < t_end else t_end) - r.due)
+        out.append((first if first is not None and first < stop else stop) - r.due)
     return out
 
 

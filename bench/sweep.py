@@ -9,8 +9,12 @@ tokens per second, the TTFT and inter-token tails, and the queue left at the
 end. The knee is the highest rate whose queue stays bounded and whose
 completed tokens still follow the offered ones; in a window long enough for
 the load to settle, the requests completed per second in its second half at
-a rate above the knee measure the knee itself. A cell's rate is fixed in its
-traffic file from such a sweep; the benchmark's runs never search for one.
+a rate above the knee measure the knee itself, so a mix's sweep ends after
+the first rate at which the queue grew (see ``queue_grew``). The mean
+number of requests in slots over the second half (``in_slots_2nd_half``) is
+what a traffic file's warm fill stands for at that rate. A cell's rate is
+fixed in its traffic file from such a sweep; the benchmark's runs never
+search for one.
 """
 
 from __future__ import annotations
@@ -22,6 +26,21 @@ import sys
 import time
 
 from bench import run, stats, traffic as traffic_mod
+
+
+def queue_grew(row: dict, seconds: float) -> bool:
+    """The backlog rose over the second half and ends above 5% of the
+    window's arrivals: more than a transient queue of a bursty mix."""
+    return (row["queued_at_end"] > row["queued_mid"]
+            and row["queued_at_end"] > 0.05 * row["rate"] * seconds)
+
+
+def in_slots(recs, a: float, b: float) -> float:
+    """Mean number of requests held in slots over [a, b): each from its
+    admission to its completion (or b)."""
+    held = sum(max(0.0, min(r.done if r.done is not None else b, b) - max(r.admitted, a))
+               for r in recs if r.admitted is not None and r.admitted < b)
+    return held / (b - a)
 
 
 def main(argv=None) -> int:
@@ -80,13 +99,17 @@ def main(argv=None) -> int:
                 / (b - half),
                 "queued_mid": queued_mid,
                 "queued_at_end": sched.admission.pending(),
+                "in_slots_2nd_half": in_slots(drv.recs, half, b),
                 "active_at_end": sum(s is not None for s in sched.slots),
                 "ticks": sum(1 for t in drv.ticks if a <= t.t0 < b),
                 "preemptions": sched.preemptions,
                 "compiled_in_process": run.cached_programs(cache_dir) > cached,
             }
+            row["queue_grew"] = queue_grew(row, args.seconds)
             print(json.dumps(row), flush=True)
             del drv
+            if row["queue_grew"]:
+                break
     return 0
 
 

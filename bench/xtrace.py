@@ -10,7 +10,9 @@ attention; a custom call's event also carries the shapes it ran with, result
 first, then each operand. The harness puts a ``bench/tick`` annotation around every
 ``Scheduler.tick()``; it lands on a host line of the same trace, on the same
 clock as the device, which ties device time to ticks and, through the
-harness's own clock, to the program's tracer spans.
+harness's own clock, to the program's tracer spans. While the program's
+tracer is on, the scheduler's own ``serve/<span>`` annotations land on the
+host lines too; they are kept, prefix cut, for bench/phases.py.
 
 Times here are nanoseconds on the profile's clock.
 """
@@ -24,6 +26,7 @@ from dataclasses import dataclass, field
 GEMM_KERNEL = "tugemm_fused_pallas"
 ATTN_KERNEL = "flash_paged_decode"
 TICK_ANNOTATION = "bench/tick"
+SERVE_PREFIX = "serve/"
 _OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
 _SHAPE = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
@@ -55,6 +58,7 @@ class Module:
 class Trace:
     modules: list            # Module, in start order, ops attached
     annotations: list        # (start, dur) of each tick annotation, in order
+    serve: list = field(default_factory=list)  # (start, end, name) of each serve/* one
 
 
 def parse_op(name: str) -> tuple[str, str]:
@@ -93,14 +97,17 @@ def from_profile(pd) -> Trace:
         while j < len(ops) and ops[j].start < end:
             m.ops.append(ops[j])
             j += 1
-    ann = []
+    ticks, serve = [], []
     for plane in pd.planes:
         if not plane.name.startswith("/host"):
             continue
-        for ln in plane.lines:
-            ann += [(e.start_ns, e.duration_ns) for e in ln.events
-                    if e.name == TICK_ANNOTATION]
-    return Trace(modules, sorted(ann))
+        for e in (e for ln in plane.lines for e in ln.events):
+            if e.name == TICK_ANNOTATION:
+                ticks.append((e.start_ns, e.duration_ns))
+            elif e.name.startswith(SERVE_PREFIX):
+                serve.append((e.start_ns, e.start_ns + e.duration_ns,
+                              e.name[len(SERVE_PREFIX):]))
+    return Trace(modules, sorted(ticks), sorted(serve, key=lambda a: (a[0], -a[1])))
 
 
 def load(trace_dir: str) -> Trace:

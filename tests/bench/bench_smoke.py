@@ -30,7 +30,8 @@ TRAFFIC = {"prompt": {"median": 40, "sigma": 0.5, "min": 8, "max": 128},
            "warm": {"fill": 2, "seconds": 0.5}, "check_tokens": 48}
 
 
-def run_smoke(monkeypatch, *, seed: int = 1, control: bool = False, seconds: float = 3.0):
+def run_smoke(monkeypatch, *, seed: int = 1, control: bool = False, seconds: float = 3.0,
+              follow: bool = False):
     """One harness run of the smoke cell on the CPU (XLA twins), no
     persistent compilation cache; returns (correct, compared, result)."""
     import jax
@@ -41,6 +42,8 @@ def run_smoke(monkeypatch, *, seed: int = 1, control: bool = False, seconds: flo
     monkeypatch.setattr(bench.weights, "STD", SMOKE_STD)
     monkeypatch.setattr(cc, "enable_compile_cache", lambda: "")
     jax.config.update("jax_enable_compilation_cache", False)
+    if follow:   # the smoke cell reports ttft_p95_s, so its first tokens are followed
+        monkeypatch.setattr(run, "end_to_end_for", lambda cell: [{"name": "ttft_p95_s"}])
     cfg = config()
     res = run.run_cell("smoke", cfg, TRAFFIC, seed=seed, seconds=seconds, trace=False,
                        control=control, expect_path="xla")

@@ -3,9 +3,11 @@ synthetic profile with a known answer: two 100 ms ticks, each with one step
 on the device from 7 to 40 ms and one argmax from 70 to 80 ms into the tick,
 a compile inside the second tick's launch, and 10 ms with no tick after."""
 
+from types import SimpleNamespace
+
 import pytest
 
-from bench import phases, xtrace
+from bench import layers, phases, run, xtrace
 
 MS = 1_000_000  # ns
 
@@ -44,14 +46,14 @@ def profile():
 
     mods = [(n, t + s, t + e) for t in (0, 100) for n, s, e in DEVICE]
     host = [("serve/" + n, t + s, t + e) for t in (0, 100) for n, s, e in TICK]
-    host.append(("bench/tick", 0, 100))
+    host += [("bench/tick", 0, 100), ("bench/tick", 100, 200)]
     text = (_plane(1, "/device:TPU:0", {"XLA Modules": mods, "XLA Ops": []}) + "\n"
             + _plane(2, "/host:CPU", {"python": host}))
     return ProfileData.from_text_proto(text)
 
 
 def test_annotations_nest_under_their_tick(profile):
-    anns = phases.annotations(profile)
+    anns = xtrace.from_profile(profile).serve
     assert len(anns) == 2 * len(TICK)               # bench/tick left out
     assert anns[0] == (0, 100 * MS, "tick") and anns[1][2] == "admit"
     labels = [label for *_, label in phases.labelled(anns[: len(TICK)])]
@@ -68,16 +70,16 @@ def test_innermost_keeps_the_parents_remainder():
 
 
 def test_device_step_idle_per_tick(profile):
-    anns = phases.annotations(profile)
-    modules = xtrace.from_profile(profile).modules
+    trace = xtrace.from_profile(profile)
+    anns, modules = trace.serve, trace.modules
     # device_step 5-60 ms with the step busy 7-40: 2 + 20 ms idle each tick
     assert phases.device_step_idle_ms(anns, modules) == pytest.approx(22.0)
     assert phases.device_step_idle_ms([], modules) is None
 
 
 def test_idle_by_innermost_span(profile):
-    anns = phases.annotations(profile)
-    modules = xtrace.from_profile(profile).modules
+    trace = xtrace.from_profile(profile)
+    anns, modules = trace.serve, trace.modules
     compile_ = [(COMPILE[0] * MS, COMPILE[1] * MS, "compile")]
     got = dict(phases.idle_by_span(phases.labelled(anns) + compile_, modules,
                                    WINDOW[0] * MS, WINDOW[1] * MS))
@@ -101,7 +103,8 @@ def _res(shift_s=3.0):
     which is ``shift_s`` on the host clock)."""
     spans = [{"ph": "X", "name": n, "pid": 1, "tid": 0, "ts": (t + s) * 1e3,
               "dur": (e - s) * 1e3} for t in (0, 100) for n, s, e in TICK]
-    spans.append({"ph": "X", "name": "decode", "pid": 2, "tid": 5, "ts": 5e3, "dur": 55e3})
+    spans.append({"ph": "X", "name": "decode", "pid": 2, "tid": 5, "ts": 5e3, "dur": 55e3,
+                  "args": {"pos": 0, "tokens": 1}})
     return {"spans": spans, "tracer_offset_s": shift_s, "t_open": shift_s,
             "t_end": shift_s + 0.2}
 
@@ -119,3 +122,21 @@ def test_host_ms_per_tick_and_logits_host_ms():
     # a record without the sub-spans (a program that lacks them) reads nothing
     bare = dict(_res(), spans=[e for e in _res()["spans"] if e["name"] in ("tick", "commit")])
     assert phases.logits_host_ms(bare) is None
+
+
+def test_the_logits_readers_read_the_harness_context(profile):
+    """logits_host_ms and device_step_idle_ms read what bench/phases.py reads,
+    from the context every per-layer reader gets: the serve/* annotations
+    kept by the trace reduction, and the tracer's record."""
+    trace = xtrace.from_profile(profile)
+    assert len(trace.annotations) == 2
+    config = run.config_file("qwen3-0.6b")
+    res = dict(_res(), ticks=[SimpleNamespace(t0=3.0, t1=3.1), SimpleNamespace(t0=3.1, t1=3.2)],
+               policy=config["quant_policy"], recs=[])
+    ctx, offset, _ = layers.build(res, config, "TPU v5 lite", trace)
+    assert offset == pytest.approx(-3e9)
+    assert run.metric_reader("device_step_idle_ms").read(ctx) == pytest.approx(22.0)
+    assert run.metric_reader("logits_host_ms").read(ctx) == pytest.approx(42.0)
+    bare = dict(res, spans=[e for e in res["spans"] if e["name"] != "sample"])
+    ctx, _, _ = layers.build(bare, config, "TPU v5 lite", trace)
+    assert run.metric_reader("logits_host_ms").read(ctx) == pytest.approx(22.0)

@@ -74,3 +74,28 @@ def test_each_config_names_a_family_on_disk(name):
     fam = run.family(config)
     assert set(config["reduced"]) <= set(fam.KEYS) and fam.GEMMS and fam.ATTENTION
     assert run.model_config(config).num_layers == config["num_hidden_layers"]
+
+
+WORKLOAD_LISTS = [(kind, m["name"]) for kind in ("end_to_end", "per_layer")
+                  for m in BENCH[kind] if "workloads" in m]
+
+
+@pytest.mark.parametrize("kind,name", WORKLOAD_LISTS, ids=lambda x: str(x))
+def test_workloads_lists_name_only_cells_that_exist(kind, name):
+    (m,) = [m for m in BENCH[kind] if m["name"] == name]
+    assert m["workloads"] and len(set(m["workloads"])) == len(m["workloads"])
+    assert set(m["workloads"]) <= set(CELLS), (name, m["workloads"])
+
+
+# each cell's end-to-end metrics, as PERF.md section 2 lists them
+END_TO_END = {
+    "qwen3-8b-l12.reasoning": {"itl_p95_s", "output_tok_s", "setup_s"},
+    "qwen3-0.6b.chat": {"itl_p95_s", "setup_s"},
+    "qwen3-8b-l12.code": {"ttft_p95_s", "itl_p95_s", "setup_s"},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(END_TO_END))
+def test_end_to_end_metrics_of_each_cell(cell):
+    assert {m["name"] for m in run.end_to_end_for(cell)} == END_TO_END[cell]
+    assert set(END_TO_END) == set(CELLS)

@@ -24,6 +24,8 @@ def test_ttft_counts_unanswered_requests_at_their_wait_so_far():
     ]
     assert sorted(stats.ttft_samples(reqs, 1.0, 10.0)) == [0.5, 6.0, 7.0]
     assert stats.percentile(stats.ttft_samples(reqs, 1.0, 10.0), 100) == 7.0
+    # followed on past the close until 12: the late answer counts whole
+    assert sorted(stats.ttft_samples(reqs, 1.0, 10.0, 12.0)) == [0.5, 7.0, 9.0]
 
 
 def test_itl_takes_gaps_with_both_tokens_in_the_window():

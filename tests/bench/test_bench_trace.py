@@ -144,3 +144,38 @@ def test_gemm_and_attention_counts():
     assert a_bytes == 128 * 2 * 8 * 128 * 2 + 2 * 16 * 128 * 2
     with pytest.raises(KeyError):
         costs.peaks("TPU v9")
+
+
+# What each reader read on these records before the program's serve/*
+# annotations were kept in the trace reduction: keeping them moves nothing.
+BEFORE = {
+    "tick_host_ms": 0.39999999999999997,
+    "step_mfu": 0.1575360826021716,
+    "decode_step_mfu": 0.1370005008676837,
+    "decode_tugemm_roofline": 56.12240003149196,
+    "decode_paged_attn_roofline": 22.568394931840217,
+    "device_idle_share": 10.518052866986704,
+    "mixed_step_mfu": 0.20584907551803197,
+    "mixed_tugemm_roofline": 26.322433048509758,
+    "step_ms.mixed": 218.65864499999998,
+}
+IDLE_GAPS_BEFORE = [["tick device_step", 0.041667419999999566],
+                    ["between ticks", 0.00209150999999963],
+                    ["tick commit", 0.0010500000000002174],
+                    ["tick, outside its phases", 0.000300000000000189],
+                    ["tick admit", 0.0001499999999996504]]
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_existing_readers_read_as_before(trace, name):
+    res, config = _records(trace)
+    ctx, _, _ = layers.build(res, config, "TPU v5 lite", trace)
+    assert run.metric_reader(name).read(ctx) == pytest.approx(BEFORE[name], rel=1e-12)
+
+
+def test_breakdown_idle_gaps_as_before(trace):
+    res, config = _records(trace)
+    ctx, offset, spans = layers.build(res, config, "TPU v5 lite", trace)
+    got = layers.breakdown(ctx, trace, offset, spans)["idle_gaps"]
+    assert [k for k, _ in got] == [k for k, _ in IDLE_GAPS_BEFORE]
+    assert [v for _, v in got] == pytest.approx([v for _, v in IDLE_GAPS_BEFORE], rel=1e-12)

@@ -73,3 +73,29 @@ def test_every_mix_on_disk_fits_its_configurations_capacity():
         mix, cap = run.traffic_file(w["traffic"]), run.config_file(w["config"])
         assert mix["prompt"]["max"] + mix["output"]["max"] <= cap["serving"]["capacity"]
         assert mix["arrival"]["rate"] > 0 and mix["users"]
+
+
+# (fill, arrivals) of one run at run_seconds, as PERF.md section 4 gives them:
+# chat 20 + 0.85 x (10 + 51), code 14 + 1.3 x (15 + 51), each part rounded
+PLANNED = {"chat": (20, 8 + 43), "code": (14, 20 + 66)}
+
+
+@pytest.mark.parametrize("mix", ["chat", "code"])
+def test_a_cells_mix_plans_rate_times_warm_up_and_window(mix):
+    """Each run of the mix offers the fill, rate x warm-up seconds and rate x
+    run_seconds arrivals, every length inside the mix's bounds."""
+    t = run.traffic_file(mix)
+    seconds = run.benchmark()["run_seconds"]
+    rate, warm = t["arrival"]["rate"], t["warm"]["seconds"]
+    planned = traffic.plan(t, seed=BIG_SEED, seconds=seconds, max_batch=32, vocab=151936)
+    fill = [p for p in planned if p.fill]
+    arr = [p for p in planned if not p.fill]
+    assert len(fill) == traffic.fill_count(t, 32) and all(p.due == 0.0 for p in fill)
+    assert sum(p.due < warm for p in arr) == round(rate * warm)
+    assert sum(p.due >= warm for p in arr) == round(rate * seconds)
+    assert max(p.due for p in arr) < warm + seconds
+    lo, hi = t["prompt"]["min"], t["prompt"]["max"]
+    assert all(lo <= len(p.prompt) <= hi for p in arr)
+    assert all(t["output"]["min"] <= p.max_new <= t["output"]["max"] for p in arr)
+    assert all(lo <= len(p.prompt) <= hi + t["output"]["max"] for p in fill)
+    assert (len(fill), len(arr)) == PLANNED[mix]
