@@ -15,6 +15,12 @@ decode read side instead:
   (``pltpu.PrefetchScalarGridSpec``), so each page streams HBM→VMEM exactly
   once, and neither the gathered intermediate nor a slice of one layer's
   pool ever exists.
+* the grid keeps its ``max_blocks`` page steps per row, but only the pages
+  below a row's ``kv_len`` are live: a dead step (a page at or after
+  ``kv_len``, every step of an idle row) skips the body under ``pl.when``,
+  and its index maps hold the row's last live page, so it neither fetches
+  nor computes. The kernel's work follows live context, not capacity; what
+  a dead step still costs is the grid step itself.
 * int8 KV dequant happens in-register per page (``int8 * scale[token]``,
   the same float ops as the XLA twin's pool dequant), fused into the
   attention inner loop.
@@ -133,61 +139,71 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # visibility mask for this page, models/flash.py semantics: row r of the
-    # (kv, n_rep, sq) query layout sits at absolute position pos[b] + (r % sq)
-    sq_idx = jax.lax.broadcasted_iota(jnp.int32, (hq, bs), 0) % sq
-    k_pos = m * bs + jax.lax.broadcasted_iota(jnp.int32, (hq, bs), 1)
-    q_pos = pos_ref[b] + sq_idx
-    mask = k_pos < len_ref[b]
-    if causal:
-        mask = mask & (k_pos <= q_pos)
-    if window is not None:
-        mask = mask & (q_pos - k_pos < window)
+    # a page at or after the row's live length is masked for every query
+    # row: it would leave m, l and acc exactly as they are (alpha = 1,
+    # p = 0), so the step skips the body, and its index maps repeat the
+    # last live page's block, so no page is fetched for it either
+    @pl.when(m * bs < len_ref[b])
+    def _attend():
+        # visibility mask for this page, models/flash.py semantics: row r of
+        # the (kv, n_rep, sq) query layout sits at absolute position
+        # pos[b] + (r % sq)
+        sq_idx = jax.lax.broadcasted_iota(jnp.int32, (hq, bs), 0) % sq
+        k_pos = m * bs + jax.lax.broadcasted_iota(jnp.int32, (hq, bs), 1)
+        q_pos = pos_ref[b] + sq_idx
+        mask = k_pos < len_ref[b]
+        if causal:
+            mask = mask & (k_pos <= q_pos)
+        if window is not None:
+            mask = mask & (q_pos - k_pos < window)
 
-    # dequantized page: K parts concatenated on features (MLA [ckv ; kr]),
-    # V taken whole — each laid out (bs, kv * per-head-features)
-    row = tables_ref[b, m] % scale_rows      # this page's row of its scale block
-    parts = [_deq(r, s, row) for r, s in zip(k_refs, ks_refs)]
-    v_page = _deq(v_ref, vs_ref, row)
+        # dequantized page: K parts concatenated on features (MLA
+        # [ckv ; kr]), V taken whole — each laid out (bs, kv * per-head
+        # features); ``row`` is this page's row of its scale block
+        row = tables_ref[b, m] % scale_rows
+        parts = [_deq(r, s, row) for r, s in zip(k_refs, ks_refs)]
+        v_page = _deq(v_ref, vs_ref, row)
 
-    # scores per kv head: q rows [g*group*sq, (g+1)*group*sq) dot that head's
-    # feature slice of every part
-    s_rows = []
-    for g in range(kv):
-        qg = q_ref[0, g * group * sq : (g + 1) * group * sq, :]
-        kg = jnp.concatenate(
-            [p[:, g * f : (g + 1) * f] for p, f in zip(parts, part_dims)], axis=-1
-        ) if len(parts) > 1 else parts[0][:, g * part_dims[0] : (g + 1) * part_dims[0]]
-        s_rows.append(
-            jax.lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
+        # scores per kv head: q rows [g*group*sq, (g+1)*group*sq) dot that
+        # head's feature slice of every part
+        s_rows = []
+        for g in range(kv):
+            qg = q_ref[0, g * group * sq : (g + 1) * group * sq, :]
+            kg = jnp.concatenate(
+                [p[:, g * f : (g + 1) * f] for p, f in zip(parts, part_dims)],
+                axis=-1,
+            ) if len(parts) > 1 else (
+                parts[0][:, g * part_dims[0] : (g + 1) * part_dims[0]])
+            s_rows.append(
+                jax.lax.dot_general(
+                    qg, kg, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
             )
-        )
-    s = jnp.concatenate(s_rows, axis=0) if kv > 1 else s_rows[0]  # (hq, bs)
-    s = jnp.where(mask, s, NEG_INF)
+        s = jnp.concatenate(s_rows, axis=0) if kv > 1 else s_rows[0]  # (hq, bs)
+        s = jnp.where(mask, s, NEG_INF)
 
-    # online softmax update (FlashAttention recurrence); masked positions
-    # get probability exactly 0 so stale page contents never leak into acc
-    m_new = jnp.maximum(m_scr[...], s.max(axis=1, keepdims=True))
-    alpha = jnp.exp(m_scr[...] - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    l_new = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        # online softmax update (FlashAttention recurrence); masked positions
+        # get probability exactly 0 so stale page contents never leak into acc
+        m_new = jnp.maximum(m_scr[...], s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_scr[...] - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_new = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
 
-    pv_rows = []
-    for g in range(kv):
-        pg = p[g * group * sq : (g + 1) * group * sq, :]
-        vg = v_page[:, g * hdv : (g + 1) * hdv]
-        pv_rows.append(
-            jax.lax.dot_general(
-                pg, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+        pv_rows = []
+        for g in range(kv):
+            pg = p[g * group * sq : (g + 1) * group * sq, :]
+            vg = v_page[:, g * hdv : (g + 1) * hdv]
+            pv_rows.append(
+                jax.lax.dot_general(
+                    pg, vg, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
             )
-        )
-    pv = jnp.concatenate(pv_rows, axis=0) if kv > 1 else pv_rows[0]  # (hq, hdv)
-    acc_scr[...] = acc_scr[...] * alpha + pv
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+        pv = jnp.concatenate(pv_rows, axis=0) if kv > 1 else pv_rows[0]  # (hq, hdv)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
+        l_scr[...] = l_new
 
     @pl.when(m == n_pages - 1)
     def _flush():
@@ -244,8 +260,15 @@ def flash_paged_decode(
     k_int8 = k_parts[0].dtype == jnp.int8
     v_int8 = v_pool.dtype == jnp.int8
 
-    def page_map(b, m, tbl, _pos, _len, lyr):
-        return (lyr[0], tbl[b, m], 0, 0)
+    def live_page(b, m, tbl, ln):
+        """Row b's page for grid step m, held at its last live page (page 0
+        for an idle row) once m passes it: a dead step repeats the block
+        index of the step before it, so the pipeline fetches nothing."""
+        last = jnp.maximum((ln[b] + bs - 1) // bs - 1, 0)
+        return tbl[b, jnp.minimum(m, last)]
+
+    def page_map(b, m, tbl, _pos, ln, lyr):
+        return (lyr[0], live_page(b, m, tbl, ln), 0, 0)
 
     # int8 page scales (L, P+1, bs) are read SCALE_ROWS pages at a time: a
     # (1, 1, bs) block is refused by Mosaic (its second-minor dim is neither
@@ -253,8 +276,8 @@ def flash_paged_decode(
     # layout the cache writes them in, so no relayout of the plane is made
     scale_rows = min(SCALE_ROWS, v_pool.shape[1])
 
-    def scale_map(b, m, tbl, _pos, _len, lyr):
-        return (lyr[0], tbl[b, m] // scale_rows, 0)
+    def scale_map(b, m, tbl, _pos, ln, lyr):
+        return (lyr[0], live_page(b, m, tbl, ln) // scale_rows, 0)
 
     in_specs = [pl.BlockSpec((1, hq, hd_tot), lambda b, m, *_: (b, 0, 0))]
     operands: list = [qf]

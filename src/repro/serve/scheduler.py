@@ -1215,7 +1215,8 @@ class Scheduler:
         # device_step ends at the host logits materialization (the sync)
         with tr.span("device_step", args={
                 "rows": len(main_rows), "width": width,
-                "tokens": int(sum(int(lens[i]) for i in scheduled))}
+                "tokens": int(sum(int(lens[i]) for i in scheduled)),
+                **self._page_steps(pos, lens)}
                 if tr.enabled else None) as step_span:
             if main_rows:
                 main_np, step_by_bits = self._main_step(
@@ -1254,6 +1255,20 @@ class Scheduler:
             self._commit(logits_np, scheduled, main_rows, fbset, pos, lens,
                          step_by_bits)
         return self._end_tick(True)
+
+    def _page_steps(self, pos, lens) -> dict:
+        """The paged kernel's grid steps in one call of this tick's step
+        (each layer makes one): ``pages_grid`` = rows × table width, and
+        ``pages_live`` = the steps below a row's live length ``pos + lens``,
+        the only ones that fetch or compute (kernels/flash_paged.py). Every
+        row counts, as in the kernel: a slot that waits this tick keeps its
+        ``pos``, so its pages are still attended. Empty without a block
+        table."""
+        if self.mgr is None:
+            return {}
+        live = -(-(pos.astype(np.int64) + lens) // self.rc.block_size)
+        return {"pages_live": int(live.sum()),
+                "pages_grid": self.max_batch * self.mgr.max_blocks}
 
     def _main_step(self, tokens, pos, lens, tables, fbset, width):
         """The target-policy step over every scheduled row outside ``fbset``.
@@ -1644,7 +1659,8 @@ class Scheduler:
             # interval includes the mirror dispatch, which is async
             _sdur = tr.ts() - _st
             tr.complete("device_step", PID_SCHED, TID_TICK, _st, _sdur, args={
-                "rows": len(scheduled), "width": int(Wv), "kind": "verify"})
+                "rows": len(scheduled), "width": int(Wv), "kind": "verify",
+                **self._page_steps(pos, vlens)})
             for i in scheduled:
                 sl = self.slots[i]
                 if sl is None:

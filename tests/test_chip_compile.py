@@ -35,6 +35,15 @@ MLA_LORA, MLA_ROPE = 512, 64
 ROWS, CHUNK = 4, 128
 PAGES, BLOCK, MAX_BLOCKS = 512, 16, 128
 LAYERS = 3  # the paged kernel reads one layer of a stacked pool
+# the benchmark cells' paged steps (bench/configs): 32 rows, 128-token
+# pages; qwen3-0.6b (16 heads, 416 pages, 40 a row) and qwen3-8b-l12 (32
+# heads, 608 pages, 65 a row), both with kv 8 and head_dim 128
+CELLS = {
+    None: dict(rows=ROWS, heads=HEADS, pages=PAGES, block=BLOCK,
+               max_blocks=MAX_BLOCKS),
+    "qwen3-0.6b": dict(rows=32, heads=16, pages=416, block=128, max_blocks=40),
+    "qwen3-8b-l12": dict(rows=32, heads=32, pages=608, block=128, max_blocks=65),
+}
 
 
 @pytest.fixture(scope="module")
@@ -97,25 +106,32 @@ def test_fused_gemm_compiles(one_chip, bits, w_quantized, k, n, m):
 
 
 @pytest.mark.parametrize(
-    "sq,kv_heads,parts,v_width",
+    "sq,kv_heads,parts,v_width,cell",
     [
-        (1, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM),
-        (CHUNK, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM),
-        (1, 1, (MLA_LORA, MLA_ROPE), MLA_LORA),
+        (1, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, None),
+        (CHUNK, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, None),
+        (1, 1, (MLA_LORA, MLA_ROPE), MLA_LORA, None),
+        (1, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, "qwen3-0.6b"),
+        (128, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, "qwen3-0.6b"),
+        (1, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, "qwen3-8b-l12"),
+        (64, KV_HEADS, (HEAD_DIM,), KV_HEADS * HEAD_DIM, "qwen3-8b-l12"),
     ],
-    ids=["gqa-decode", "gqa-prefill", "mla-decode"],
+    ids=["gqa-decode", "gqa-prefill", "mla-decode",
+         "qwen3-0.6b-decode", "qwen3-0.6b-mixed",
+         "qwen3-8b-l12-decode", "qwen3-8b-l12-mixed"],
 )
 @pytest.mark.parametrize("kv_dtype", [jnp.int8, jnp.bfloat16], ids=["int8", "bf16"])
-def test_flash_paged_compiles(one_chip, sq, kv_heads, parts, v_width, kv_dtype):
+def test_flash_paged_compiles(one_chip, sq, kv_heads, parts, v_width, cell, kv_dtype):
     int8 = kv_dtype == jnp.int8
-    q = _spec(one_chip, (ROWS, sq, HEADS, sum(parts)), jnp.bfloat16)
-    k_parts = tuple(_spec(one_chip, (LAYERS, PAGES + 1, BLOCK, kv_heads * f), kv_dtype)
-                    for f in parts)
-    scale = _spec(one_chip, (LAYERS, PAGES + 1, BLOCK), jnp.float32) if int8 else None
+    c = CELLS[cell]
+    rows, pool = c["rows"], (LAYERS, c["pages"] + 1, c["block"])
+    q = _spec(one_chip, (rows, sq, c["heads"], sum(parts)), jnp.bfloat16)
+    k_parts = tuple(_spec(one_chip, (*pool, kv_heads * f), kv_dtype) for f in parts)
+    scale = _spec(one_chip, pool, jnp.float32) if int8 else None
     k_scales = tuple(scale for _ in parts)
-    v_pool = _spec(one_chip, (LAYERS, PAGES + 1, BLOCK, v_width), kv_dtype)
-    tables = _spec(one_chip, (ROWS, MAX_BLOCKS), jnp.int32)
-    vec = _spec(one_chip, (ROWS,), jnp.int32)
+    v_pool = _spec(one_chip, (*pool, v_width), kv_dtype)
+    tables = _spec(one_chip, (rows, c["max_blocks"]), jnp.int32)
+    vec = _spec(one_chip, (rows,), jnp.int32)
     layer = _spec(one_chip, (), jnp.int32)
 
     def attend(q, k_parts, k_scales, v_pool, v_scale, tables, pos, kv_len, layer):
@@ -126,7 +142,7 @@ def test_flash_paged_compiles(one_chip, sq, kv_heads, parts, v_width, kv_dtype):
     compiled = jax.jit(attend).lower(*args).compile()
     assert _tpu_kernels(compiled) == 1
     out = jax.eval_shape(attend, *args)
-    assert out.shape == (ROWS, sq, HEADS, v_width // kv_heads)
+    assert out.shape == (rows, sq, c["heads"], v_width // kv_heads)
 
 
 # ------------------------------------------------- the serving step, in place

@@ -85,13 +85,17 @@ def _view(rows, bs, MB, P, seed=0, layer=1):
 
 
 def _gqa_case(rows, *, kv=2, group=3, hd=8, sq=1, bs=4, MB=3, int8=True,
-              window=None, seed=0, layer=1):
+              window=None, seed=0, layer=1, prep=None):
+    """Kernel output and twin output for one GQA case; ``prep(cache,
+    view)``, if given, rewrites the pools both read."""
     B = len(rows)
     P = B * MB
     view = _view(rows, bs, MB, P, seed=seed, layer=layer)
     kc = _pool(P, bs, (kv, hd), int8, seed + 1)
     vc = {k.replace("k", "v", 1): v for k, v in _pool(P, bs, (kv, hd), int8, seed + 2).items()}
     cache = {**kc, **vc}
+    if prep is not None:
+        cache = prep(cache, view)
     q = jnp.asarray(np.random.default_rng(seed + 3)
                     .standard_normal((B, sq, kv * group, hd)).astype(np.float32))
 
@@ -146,8 +150,15 @@ def test_idle_rows_emit_zeros():
 def test_mla_kernel_matches_twin(int8, sq, layer):
     """Two K parts concatenated per page in-register ([ckv ; kr], single
     latent head), V = the ckv pool — the absorbed-decode MLA layout."""
+    out, ref = _mla_case([(5, sq), (0, 0), (11 - sq, sq)], sq=sq, int8=int8,
+                         layer=layer)
+    np.testing.assert_allclose(out, ref, atol=TOL, rtol=0)
+
+
+def _mla_case(rows, *, sq, int8, layer=1, prep=None):
+    """Kernel output and twin output for one MLA case (MB 4, bs 4);
+    ``prep(cache, view)``, if given, rewrites the pools both read."""
     lora, rope_d, h = 32, 16, 4
-    rows = [(5, sq), (0, 0), (11 - sq, sq)]
     B, bs, MB = len(rows), 4, 4
     P = B * MB
     view = _view(rows, bs, MB, P, seed=7, layer=layer)
@@ -156,6 +167,8 @@ def test_mla_kernel_matches_twin(int8, sq, layer):
     cache = {"ckv": ckv["k"], "kr": kr["kr"]}
     if int8:
         cache["ckv_scale"], cache["kr_scale"] = ckv["k_scale"], kr["kr_scale"]
+    if prep is not None:
+        cache = prep(cache, view)
     q = jnp.asarray(np.random.default_rng(10)
                     .standard_normal((B, sq, h, lora + rope_d)).astype(np.float32))
 
@@ -172,7 +185,50 @@ def test_mla_kernel_matches_twin(int8, sq, layer):
     k_eff = jnp.concatenate([ckv_full, kr_full], axis=-1)[:, :, None, :]
     ref = blockwise_attention(q, k_eff, ckv_full[:, :, None, :],
                               q_offset=view.pos, kv_len=view.kv_len, causal=True)
-    np.testing.assert_allclose(out, np.asarray(ref), atol=TOL, rtol=0)
+    return np.asarray(out), np.asarray(ref)
+
+
+# ------------------------------------------------------- dead pages unread
+def _poison_dead(cache: dict, view: KVView) -> dict:
+    """Every page that holds no live key of any row (the pages wholly at or
+    after a row's kv_len: the trash page and every page no live table entry
+    names) filled with NaN in every layer: in the token scales of an int8
+    pool, else in the data, which is stored as bf16. A kernel that adds
+    such a page into its sums, even with probability 0, returns NaN."""
+    tables, kv_len = np.asarray(view.tables), np.asarray(view.kv_len)
+    bs = view.block_size
+    live = {int(tables[b, m]) for b in range(len(kv_len))
+            for m in range(-(-int(kv_len[b]) // bs))}
+    int8 = any(n.endswith("_scale") for n in cache)
+    out = {}
+    for name, buf in cache.items():
+        if buf.dtype == jnp.int8:
+            out[name] = buf
+            continue
+        arr = np.asarray(buf, np.float32).copy()
+        dead = [p for p in range(arr.shape[1]) if p not in live]
+        arr[:, dead] = np.nan
+        out[name] = jnp.asarray(arr, jnp.float32 if int8 else jnp.bfloat16)
+    return out
+
+
+@pytest.mark.parametrize("sq", [1, 4], ids=["decode", "mixed"])
+@pytest.mark.parametrize("int8", [True, False], ids=["int8", "bf16"])
+@pytest.mark.parametrize("family", ["gqa", "mla"])
+def test_dead_pages_are_never_read(family, int8, sq):
+    """Pages at or after a row's kv_len, and the trash page, hold NaN: the
+    kernel must neither fetch them into its sums nor let them reach the
+    output, and must still match the twin (which masks them with
+    ``where``). Rows: idle, partial last page, ending on a page boundary,
+    one short of a full table (MB 4, bs 4)."""
+    rows = [(0, 0), (5, sq), (8 - sq, sq), (15 - sq, sq)]
+    if family == "gqa":
+        out, ref = _gqa_case(rows, sq=sq, MB=4, int8=int8, prep=_poison_dead)
+    else:
+        out, ref = _mla_case(rows, sq=sq, int8=int8, prep=_poison_dead)
+    assert np.isfinite(ref).all()
+    assert np.isfinite(out).all(), np.argwhere(~np.isfinite(out))[:4]
+    np.testing.assert_allclose(out, ref, atol=TOL, rtol=0)
 
 
 # ------------------------------------------------------- split-K edge cases

@@ -241,6 +241,35 @@ def test_plain_tick_sub_spans_nest_in_order(model):
                            "device_step", "commit"]
 
 
+def test_device_step_counts_live_page_steps(model):
+    """The plain tick's device_step span carries the paged kernel's grid
+    steps: ``pages_grid`` = max_batch × max_blocks and ``pages_live`` =
+    sum over rows of ceil((pos + lens) / block_size). A scripted tick with
+    one decode row (pos 6: 7 tokens, 2 pages), one prefill chunk (pos 0,
+    4 tokens: 1 page) and one idle slot reads 3 of 3 × 8."""
+    cfg, params = model
+    tr = Tracer()
+    s = Scheduler(cfg, RC, params, capacity=32, max_batch=3, temperature=0.0,
+                  tracer=tr)
+    s.submit(Request(rid=0, prompt=list(range(1, 7)), max_new=4))
+    s.tick()                          # rid 0 prefills tokens 0..3
+    s.tick()                          # rid 0 prefills 4..5, first token
+    s.submit(Request(rid=1, prompt=list(range(1, 11)), max_new=2))
+    s.tick()                          # rid 0 decodes at 6, rid 1 prefills 0..3
+    events = tr.to_dict()["traceEvents"]
+    step = [e for e in events if e.get("ph") == "X" and e["pid"] == PID_SCHED
+            and e["name"] == "device_step"][-1]
+    rows = {e["name"]: (e["args"]["pos"], e["args"]["tokens"]) for e in events
+            if e.get("ph") == "X" and e["name"] in ("prefill", "decode")
+            and e["ts"] == step["ts"]}
+    assert rows == {"decode": (6, 1), "prefill": (0, 4)}
+    assert sum(sl is None for sl in s.slots) == 1
+    bs = RC.block_size
+    assert step["args"]["pages_grid"] == 3 * (32 // bs) == 24
+    assert step["args"]["pages_live"] == sum(
+        -(-(p + n) // bs) for p, n in rows.values()) == 3
+
+
 def test_phases_land_on_the_profilers_host_line(model, tmp_path):
     """Under a jax.profiler capture every scheduler-track span is also a
     serve/<name> annotation on the host line: one serve/device_step a step."""
